@@ -34,10 +34,12 @@ executable per jitted path after two full streams).  Results land in
 BENCH_sharded.json; CI compares the quick run against
 benchmarks/baselines/BENCH_sharded.quick.json.
 
-The measurement runs in a SUBPROCESS: ``--xla_force_host_platform_device_
-count`` is read once at backend initialisation, and by the time
-``benchmarks.run`` reaches this bench an earlier bench has usually already
-initialised a single-device backend.
+Where the backend already has ``SHARDS`` devices (a 4-chip host) the
+measurement runs in this process: a chip belongs to one process, and the
+parent holds it.  The CPU rehearsal runs it in a SUBPROCESS instead:
+``--xla_force_host_platform_device_count`` is read once at backend
+initialisation, and by the time ``benchmarks.run`` reaches this bench an
+earlier bench has usually already initialised a single-device backend.
 """
 
 from __future__ import annotations
@@ -59,7 +61,16 @@ P99_BOUND = 1.5  # acceptance: sharded p99 <= 1.5x the single-device p99
 
 
 def run_sharded(out_path: str = "BENCH_sharded.json", quick: bool = False):
-    """Spawn the measurement child with the forced device count, collect."""
+    """Measure in-process on >= SHARDS devices; on a CPU backend spawn the
+    measurement child with the forced device count, collect."""
+    import jax
+
+    if jax.device_count() >= SHARDS:
+        return _measure(out_path, quick)
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"the sharded bench needs {SHARDS} devices, found "
+            f"{jax.device_count()} {jax.default_backend()} device(s)")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={SHARDS}")
@@ -82,6 +93,7 @@ def _measure(out_path: str, quick: bool):
                                         build_local_subgraphs)
     from repro.core.metrics import speedup_model
     from repro.data.synthetic import lda_like_histograms, split_queries
+    from repro.launch.mesh import make_auto_mesh
     from repro.launch.serve import latency_stats
 
     n, n_req, dim = (2048, 96, 32) if quick else (4096, 192, 32)
@@ -93,7 +105,7 @@ def _measure(out_path: str, quick: bool):
     from repro.core import get_distance
 
     dist = get_distance("kl")
-    mesh = jax.make_mesh((SHARDS,), ("data",))
+    mesh = make_auto_mesh((SHARDS,), ("data",))
 
     def serve(sched):
         """Two full streams on the tick clock + one wall-clock stream."""

@@ -24,6 +24,7 @@ assert exact equality of beams, eval counts and hop counts).
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -494,6 +495,13 @@ def make_step_searcher(
     runs the per-query adaptive frontier policy inside the while_loop
     (``frontier`` becomes the maximum width).
 
+    ``search`` is ``functools.partial(jitted, consts, rows, neighbors,
+    entries)``: the corpus, its kernel row view (``kernels.ops.kernel_rows``,
+    laid out here once; None on the jnp path) and the graph reach the
+    executable as ARGUMENTS — closed over, jit would bake them into the
+    program as constants (gigabytes at a deployment's corpus size).
+    ``search.func.lower(*search.args, Q)`` lowers the step.
+
     ``use_pallas``: None routes scoring through the fused Pallas
     gather+distance kernel on TPU and the jnp einsum path elsewhere; True
     forces the kernel (interpret mode off-TPU); False forces jnp.  The kernel
@@ -513,11 +521,14 @@ def make_step_searcher(
     # (not ops' einsum oracle): it is the parity reference — the same floats
     # in the same reduction order as beam_search_impl.
     kernel_ok = isinstance(dist, Distance) and use_pallas is not False
+    rows = None
     if kernel_ok:
-        from repro.kernels.ops import frontier_gather_scores
+        from repro.kernels.ops import frontier_gather_scores, kernel_rows
+
+        rows = kernel_rows(dist, consts, use_pallas)
 
     @jax.jit
-    def search(Q):
+    def search(consts, rows, neighbors, entries, Q):
         B = Q.shape[0]
         qc = jax.vmap(dist.prep_query)(Q)
 
@@ -525,7 +536,7 @@ def make_step_searcher(
             def score_rows(ids):
                 return frontier_gather_scores(
                     dist, ids, qc["rep"], qc["bias"], consts["rep"], consts["bias"],
-                    use_pallas=use_pallas,
+                    x_rows=rows,
                 )
         else:
             def score_rows(ids):
@@ -539,4 +550,4 @@ def make_step_searcher(
         )
         return st.beam_d[:, :k], st.beam_i[:, :k], st.n_evals, st.hops
 
-    return search
+    return functools.partial(search, consts, rows, neighbors, entries)

@@ -38,7 +38,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .batched_beam import batched_beam_search
@@ -235,11 +234,10 @@ def build_swgraph_wave(
     adj_d = jnp.full((n, M_max), INF, jnp.float32)
     entries = jnp.zeros((1,), jnp.int32)
 
-    kernel_path = isinstance(dist, Distance) and (
-        use_pallas is True or (use_pallas is None and jax.default_backend() == "tpu")
-    )
-    if kernel_path:
-        from repro.kernels.ops import frontier_gather_scores
+    from repro.kernels import ops
+
+    # the kernel's row view of the corpus, laid out once per build
+    x_rows = ops.kernel_rows(dist, consts, use_pallas)
 
     def wave_step(carry, pids):
         adj, adj_d = carry
@@ -248,12 +246,12 @@ def build_swgraph_wave(
         safe_p = jnp.where(ok_pt, pids, 0)
         qc = jax.tree.map(lambda a: a[safe_p], qc_all)
 
-        if kernel_path:
+        if x_rows is not None:
 
             def score_rows(ids):
-                return frontier_gather_scores(
+                return ops.frontier_gather_scores(
                     dist, ids, qc["rep"], qc["bias"], consts["rep"], consts["bias"],
-                    use_pallas=use_pallas,
+                    x_rows=x_rows,
                 )
         else:
 
@@ -305,8 +303,8 @@ def build_sharded(
     matmul-form block and keeps its best ``cross_links`` REMOTE edges.
 
     Returns a (n, M_local + cross_links) int32 adjacency in GLOBAL row ids,
-    sharded like X — gather/replicate it to search the stitched graph with
-    the standard engines, or keep it sharded for scatter-gather serving.
+    replicated over ``mesh`` — so the single-device engines gather from it
+    directly, whatever the mesh's axis types.
     """
     from .nndescent import build_nndescent
 
@@ -359,13 +357,13 @@ def build_sharded(
         neg, pos = jax.lax.top_k(-D, min(cross_links, all_gids.shape[0]))
         cross = jnp.where(jnp.isfinite(neg), all_gids[pos], -1)
         local_global = jnp.where(nbrs >= 0, nbrs + shard * n_local, -1)
-        return jnp.concatenate([local_global, cross], axis=1)
+        stitched = jnp.concatenate([local_global, cross], axis=1)
+        return jax.lax.all_gather(stitched, db_axes, axis=0, tiled=True)
 
-    db_spec = P(db_axes, None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(db_spec, P()),
-        out_specs=db_spec,
-        check_rep=False,
+        in_specs=(P(db_axes, None), P()),
+        out_specs=P(),
+        check_vma=False,
     )(X_sharded, key)

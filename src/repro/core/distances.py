@@ -44,6 +44,16 @@ POST_L2 = 3
 _TINY = 1e-30
 EPS = 1e-6  # histogram floor; matches the data generators
 
+# Every matmul that decides a ranking runs at full f32 precision.  XLA's
+# default on a TPU passes f32 operands through bf16, and a KL distance is a
+# small remainder of two large terms (x . -log q + sum x log x): at bf16 the
+# exact scan that every recall figure divides by would rank wrongly.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=HIGHEST)
+
 
 def apply_post(post_id: int, s, bias_l, bias_r, c0: float = 0.0):
     """Apply a post-combine. ``bias_l``/``bias_r`` broadcast against ``s``.
@@ -93,7 +103,7 @@ class Distance:
 
     def matrix(self, U, V):
         """D[i, j] = d(U[i], V[j]) via one matmul."""
-        s = self.prep_left(U) @ self.prep_right(V).T
+        s = _mm(self.prep_left(U), self.prep_right(V).T)
         return apply_post(
             self.post_id, s, self.bias_left(U)[:, None], self.bias_right(V)[None, :], self.c0
         )
@@ -106,12 +116,12 @@ class Distance:
         Result is (B, N) either way.
         """
         if mode == "left":
-            s = self.prep_right(Q) @ self.prep_left(X).T
+            s = _mm(self.prep_right(Q), self.prep_left(X).T)
             return apply_post(
                 self.post_id, s, self.bias_left(X)[None, :], self.bias_right(Q)[:, None], self.c0
             )
         elif mode == "right":
-            s = self.prep_left(Q) @ self.prep_right(X).T
+            s = _mm(self.prep_left(Q), self.prep_right(X).T)
             return apply_post(
                 self.post_id, s, self.bias_left(Q)[:, None], self.bias_right(X)[None, :], self.c0
             )
@@ -138,7 +148,7 @@ class Distance:
 
     def score(self, rows, qc):
         """rows: pytree from prep_scan gathered to (B, ...); qc: from prep_query."""
-        s = rows["rep"] @ qc["rep"]
+        s = _mm(rows["rep"], qc["rep"])
         return apply_post(self.post_id, s, rows["bias"], qc["bias"], self.c0)
 
 

@@ -38,7 +38,6 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -132,11 +131,11 @@ def sharded_knn_scan(mesh, dist, Q, X_sharded, k: int, db_axes=("data",)):
         return _merge(all_d, all_i, k)
 
     db_spec = P(db_axes, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None), db_spec),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False,
+        check_vma=False,
     )(Q, X_pad)
 
 
@@ -208,11 +207,11 @@ def sharded_graph_search(mesh, dist, Q, X_sharded, neighbors_sharded, k: int,
         return d, i, jax.lax.psum(evals, db_axes)
 
     db_spec = P(db_axes, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None), db_spec, db_spec),
         out_specs=(P(None, None), P(None, None), P(None)),
-        check_rep=False,
+        check_vma=False,
     )(Q, X_pad, neighbors_sharded)
 
 
@@ -251,11 +250,11 @@ def build_local_subgraphs(mesh, dist, X_sharded, db_axes=("data",), NN: int = 15
             nbrs, _ = build_nndescent(dist, X_local, key, K=NN, iters=nnd_iters)
         return nbrs
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(db_axes, None), P(None)),
         out_specs=P(db_axes, None),
-        check_rep=False,
+        check_vma=False,
     )(X_pad, key)
 
 
@@ -357,13 +356,13 @@ class ShardedSlotScheduler(SchedulerHost):
         consts_shape = jax.eval_shape(
             dist.prep_scan,
             jax.ShapeDtypeStruct((self.n_local, self.dim), X_pad.dtype))
-        self._consts = shard_map(
+        self._consts = jax.shard_map(
             dist.prep_scan, mesh=mesh,
             in_specs=(P(self.db_axes, None),),
             out_specs=jax.tree.map(
                 lambda s: P(self.db_axes, *([None] * (len(s.shape) - 1))),
                 consts_shape),
-            check_rep=False,
+            check_vma=False,
         )(X_pad)
         self._dtype = jax.tree.leaves(self._consts)[0].dtype
         # SchedulerHost contract: single full-fidelity rung, no QoS ladder
@@ -489,24 +488,24 @@ class ShardedSlotScheduler(SchedulerHost):
             glob_i = jnp.full((S, k), -1, jnp.int32)
             return core, qc, glob_d, glob_i
 
-        self._init = jax.jit(shard_map(
+        self._init = jax.jit(jax.shard_map(
             init, mesh=mesh,
             in_specs=(repl2,),
             out_specs=(core_spec, qc_spec, repl2, repl2),
-            check_rep=False,
+            check_vma=False,
         ))
-        self._admit = jax.jit(shard_map(
+        self._admit = jax.jit(jax.shard_map(
             admit, mesh=mesh,
             in_specs=(core_spec, qc_spec, repl2, repl2, repl2, repl1,
                       consts_spec),
             out_specs=(core_spec, qc_spec, repl2, repl2),
-            check_rep=False,
+            check_vma=False,
         ))
-        self._step = jax.jit(shard_map(
+        self._step = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(core_spec, qc_spec, consts_spec, nbrs_spec),
             out_specs=(core_spec, repl2, repl2, repl1, repl1, repl1),
-            check_rep=False,
+            check_vma=False,
         ))
 
     # ----------------------------------------------------------- state mgmt

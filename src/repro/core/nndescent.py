@@ -25,21 +25,23 @@ from .distances import Distance
 INF = jnp.inf
 
 
-def _score_rows(dist, consts, qc_all, ids):
+def _score_rows(dist, consts, x_rows, qc_all, ids):
     """d_build(X[ids[i, c]], X[i]) for every node i, candidate c. (n, C).
 
     Plain matmul-form Distances route through the fused gather+score kernel
-    (``repro.kernels.frontier_gather``: MXU matvec per node on TPU, one fused
-    einsum elsewhere); composite/symmetrized distances take the generic
-    pytree path.  ``qc_all`` is the whole database prepped as queries ONCE
-    per build (``jax.vmap(dist.prep_query)(X)``).
+    (``repro.kernels.frontier_gather``, given its row view ``x_rows`` of the
+    corpus: MXU contraction per node on TPU) or one fused einsum elsewhere;
+    composite/symmetrized distances take the generic pytree path.
+    ``qc_all`` is the whole database prepped as queries ONCE per build
+    (``jax.vmap(dist.prep_query)(X)``).
     """
     safe = jnp.where(ids >= 0, ids, 0)
     if isinstance(dist, Distance):
         from repro.kernels.ops import frontier_gather_scores
 
         return frontier_gather_scores(
-            dist, safe, qc_all["rep"], qc_all["bias"], consts["rep"], consts["bias"]
+            dist, safe, qc_all["rep"], qc_all["bias"], consts["rep"],
+            consts["bias"], x_rows=x_rows,
         ).astype(jnp.float32)
     rows = jax.tree.map(lambda a: a[safe], consts)
     return jax.vmap(dist.score)(rows, qc_all).astype(jnp.float32)
@@ -47,16 +49,42 @@ def _score_rows(dist, consts, qc_all, ids):
 
 def _dedup_topk(d, ids, K: int):
     """Per-row: drop duplicate ids (keep best), return K smallest by d."""
-    # sort by id; mark repeats as +inf; then sort by distance
-    order = jnp.argsort(ids, axis=1)
-    ids_s = jnp.take_along_axis(ids, order, axis=1)
-    d_s = jnp.take_along_axis(d, order, axis=1)
+    # sort by id; mark repeats as +inf; then sort by distance.  Both are
+    # stable key-value sorts: an argsort + gather formulation of the same
+    # thing takes minutes for the chip's compiler at some row counts
+    ids_s, d_s = jax.lax.sort((ids, d), dimension=1, num_keys=1)
     dup = jnp.concatenate(
         [jnp.zeros((ids.shape[0], 1), bool), ids_s[:, 1:] == ids_s[:, :-1]], axis=1
     )
     d_s = jnp.where(dup | (ids_s < 0), INF, d_s)
-    sel = jnp.argsort(d_s, axis=1)[:, :K]
-    return jnp.take_along_axis(d_s, sel, axis=1), jnp.take_along_axis(ids_s, sel, axis=1)
+    d_o, i_o = jax.lax.sort((d_s, ids_s), dimension=1, num_keys=1)
+    return d_o[:, :K], i_o[:, :K]
+
+
+def _take(a, rows):
+    # in-bounds row gather; the default (fill) mode of ``a[rows]`` costs the
+    # chip's compiler close to a minute on some shapes
+    return jnp.take(a, rows, axis=0, mode="clip")
+
+
+def _join_rows(dist, consts, x_rows, qc_all, adj_d, adj, rev, rnd, rows,
+               K: int):
+    """One refinement of the node block ``rows`` against the previous
+    round's graph: (len(rows), K) best (dists, ids) over the old lists and
+    the block's neighbor-of-neighbor / reverse / random candidates."""
+    safe = jnp.where(adj >= 0, adj, 0)
+    # adj[adj[rows]] as ONE scalar gather from the flat list: the row-gather
+    # form takes the chip's compiler minutes at K = 30
+    hop = (_take(safe, rows)[:, :, None] * K + jnp.arange(K, dtype=jnp.int32)).reshape(-1)
+    two_hop = _take(safe.reshape(-1), hop).reshape(rows.shape[0], K * K)
+    cand = jnp.concatenate([two_hop, _take(rev, rows), _take(rnd, rows)], axis=1)
+    cand = jnp.where(cand == rows[:, None], -1, cand)  # no self loops
+    qc = jax.tree.map(lambda a: _take(a, rows), qc_all)
+    cand_d = _score_rows(dist, consts, x_rows, qc, cand)
+    cand_d = jnp.where(cand >= 0, cand_d, INF)
+    all_d = jnp.concatenate([_take(adj_d, rows), cand_d], axis=1)
+    all_i = jnp.concatenate([_take(adj, rows), cand], axis=1)
+    return _dedup_topk(all_d, all_i, K)
 
 
 def _sampled_reverse(adj, K_rev: int, key):
@@ -76,8 +104,16 @@ def _sampled_reverse(adj, K_rev: int, key):
     return rev.at[dst.reshape(-1), slots.reshape(-1)].max(src.reshape(-1), mode="drop")
 
 
+# nodes joined per block within a NN-descent round: bounds the join's
+# (block, K*K + K + n_random) transients, which at n = 10^6 would otherwise
+# take most of a 16 GB chip.  Every block reads the previous round's graph,
+# so the result does not depend on it.
+_JOIN_ROWS = 131072
+
+
 @functools.partial(
-    jax.jit, static_argnames=("dist", "K", "iters", "n_random", "M_out", "add_reverse")
+    jax.jit,
+    static_argnames=("dist", "K", "iters", "n_random", "M_out", "add_reverse"),
 )
 def build_nndescent(
     dist,
@@ -93,33 +129,44 @@ def build_nndescent(
 
     ``M_out`` defaults to 2K when ``add_reverse`` (forward + sampled reverse
     edges - undirected graphs searched better in the paper's refs [20]).
+    Each round joins the nodes in blocks of ``_JOIN_ROWS``.
     """
     n = X.shape[0]
     K = min(K, n - 1)
     consts = dist.prep_scan(X)
     qc_all = jax.vmap(dist.prep_query)(X)  # whole DB prepped as queries once
+    from repro.kernels.ops import kernel_rows
+
+    x_rows = kernel_rows(dist, consts)  # the kernel's corpus view, once
     iota = jnp.arange(n, dtype=jnp.int32)
 
     # --- init: random neighbors (exclude self by +1 shift mod n) ---
     key, k0 = jax.random.split(key)
     init_ids = (iota[:, None] + 1 + jax.random.randint(k0, (n, K), 0, n - 1)) % n
-    init_d = _score_rows(dist, consts, qc_all, init_ids)
+    init_d = _score_rows(dist, consts, x_rows, qc_all, init_ids)
     adj_d, adj = _dedup_topk(init_d, init_ids, K)
+
+    n_blocks = -(-n // _JOIN_ROWS)
+    # tail block rows repeat node n-1; their duplicate results are sliced off
+    blocks = jnp.minimum(
+        jnp.arange(n_blocks * min(_JOIN_ROWS, n), dtype=jnp.int32), n - 1
+    ).reshape(n_blocks, -1)
 
     def round_(carry, key_r):
         adj_d, adj = carry
         k1, k2 = jax.random.split(key_r)
-        safe = jnp.where(adj >= 0, adj, 0)
-        two_hop = safe[safe.reshape(-1)].reshape(n, K * K)
         rev = _sampled_reverse(adj, K, k1)
         rnd = jax.random.randint(k2, (n, n_random), 0, n)
-        cand = jnp.concatenate([two_hop, rev, rnd], axis=1)
-        cand = jnp.where(cand == iota[:, None], -1, cand)  # no self loops
-        cand_d = _score_rows(dist, consts, qc_all, cand)
-        cand_d = jnp.where(cand >= 0, cand_d, INF)
-        all_d = jnp.concatenate([adj_d, cand_d], axis=1)
-        all_i = jnp.concatenate([adj, cand], axis=1)
-        new_d, new_i = _dedup_topk(all_d, all_i, K)
+
+        def join(rows):
+            return _join_rows(dist, consts, x_rows, qc_all, adj_d, adj, rev,
+                              rnd, rows, K)
+
+        if n_blocks == 1:
+            new_d, new_i = join(iota)
+        else:
+            new_d, new_i = jax.lax.map(join, blocks)
+            new_d, new_i = new_d.reshape(-1, K)[:n], new_i.reshape(-1, K)[:n]
         n_changed = jnp.sum(new_i != adj)
         return (new_d, new_i), n_changed
 

@@ -580,6 +580,7 @@ class SlotScheduler(SchedulerHost):
         self._dtype = jax.tree.leaves(g.consts)[0].dtype
         self._use_pallas = use_pallas
         self._kernel_ok = isinstance(dist, Distance) and use_pallas is not False
+        self._rows_of, self._rows = None, None  # see _kernel_rows
 
         # ---- QoS: demotion ladder, admission control, tenant fairness
         rungs = [r if isinstance(r, Rung) else Rung(**r) for r in ladder or []]
@@ -613,16 +614,28 @@ class SlotScheduler(SchedulerHost):
 
     # ------------------------------------------------------------- jit setup
 
-    def _score_fn(self, consts, qc):
+    def _kernel_rows(self, consts):
+        """The kernel's row view of ``consts`` (None on the jnp path), laid
+        out once per corpus snapshot: a static index hands back the same
+        consts every tick, a mutable one new consts after a mutation."""
+        if not self._kernel_ok:
+            return None
+        if consts is not self._rows_of:
+            from repro.kernels.ops import kernel_rows
+
+            self._rows_of = consts
+            self._rows = kernel_rows(self.dist, consts, self._use_pallas)
+        return self._rows
+
+    def _score_fn(self, consts, rows, qc):
         dist = self.dist
         if self._kernel_ok:
             from repro.kernels.ops import frontier_gather_scores
-            use_pallas = self._use_pallas
 
             def score_rows(ids):
                 return frontier_gather_scores(
                     dist, ids, qc["rep"], qc["bias"], consts["rep"],
-                    consts["bias"], use_pallas=use_pallas,
+                    consts["bias"], x_rows=rows,
                 )
         else:
 
@@ -638,10 +651,10 @@ class SlotScheduler(SchedulerHost):
         patience = self.patience
         qos, any_adaptive = self._qos, self._any_adaptive
 
-        def admit(state: SlotState, Q_new, write, consts, entries, alive,
-                  ef_new, ad_new):
+        def admit(state: SlotState, Q_new, write, consts, rows, entries,
+                  alive, ef_new, ad_new):
             qc_new = jax.vmap(dist.prep_query)(Q_new)
-            score_rows = self._score_fn(consts, qc_new)
+            score_rows = self._score_fn(consts, rows, qc_new)
             fresh = seed_beams(score_rows, entries, S, ef, n, alive=alive)
             if qos:
                 # demoted slots seed exactly like an ef_new-wide engine:
@@ -673,8 +686,8 @@ class SlotScheduler(SchedulerHost):
                 adapt=jnp.where(write, ad_new, state.adapt),
             )
 
-        def step(state: SlotState, neighbors, consts):
-            score_rows = self._score_fn(consts, state.qc)
+        def step(state: SlotState, neighbors, consts, rows):
+            score_rows = self._score_fn(consts, rows, state.qc)
             core, t_cur, stall, worst = (state.core, state.t_cur, state.stall,
                                          state.worst)
             ef_act = state.ef_act if qos else None
@@ -815,7 +828,8 @@ class SlotScheduler(SchedulerHost):
             if write.any():
                 self.state = self._admit(
                     self.state, jnp.asarray(Q_new, self._dtype),
-                    jnp.asarray(write), g.consts, g.entries, g.alive,
+                    jnp.asarray(write), g.consts,
+                    self._kernel_rows(g.consts), g.entries, g.alive,
                     jnp.asarray(ef_new), jnp.asarray(ad_new),
                 )
         if (self._background is not None and not self._n_pending
@@ -826,7 +840,8 @@ class SlotScheduler(SchedulerHost):
         if not (self._slot_rid >= 0).any():
             return shed_out
 
-        self.state = self._step(self.state, g.neighbors, g.consts)
+        self.state = self._step(self.state, g.neighbors, g.consts,
+                                self._kernel_rows(g.consts))
 
         done = np.asarray(self.state.core.done)  # syncs the step
         finished = done & (self._slot_rid >= 0)

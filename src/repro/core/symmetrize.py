@@ -84,9 +84,9 @@ class ReversedDistance:
         }
 
     def score(self, rows, qc):
-        from .distances import apply_post
+        from .distances import HIGHEST, apply_post
 
-        s = rows["rep"] @ qc["rep"]
+        s = jnp.matmul(rows["rep"], qc["rep"], precision=HIGHEST)
         # left-mode d_rev(x, q) = d(q, x): q is the LEFT argument of base.
         return apply_post(self.base.post_id, s, qc["bias"], rows["bias"], self.base.c0)
 

@@ -1,9 +1,8 @@
 """Pallas TPU kernels for the distance hot path (DESIGN.md SS2.1-2.2).
 
 distance_matrix: MXU-tiled brute-force/construction block (compute-bound)
-gather_topk:     scalar-prefetch fused neighbor gather+score (DMA-bound)
-frontier_gather: per-query DMA row gather + one MXU matvec for the batched
-                 beam engine's (B, frontier*M) lock-step expansion
+frontier_gather: per-query DMA row gather + one MXU contraction for the
+                 batched beam engine's (B, frontier*M) lock-step expansion
 ops:             jitted wrappers (interpret off-TPU, compiled on TPU)
 ref:             pure-jnp oracles every kernel is tested against
 """

@@ -48,6 +48,7 @@ def _kernel_whole_k(q_ref, x_ref, qb_ref, xb_ref, o_ref, *, post_id: int, c0: fl
     s = jnp.dot(
         q_ref[...].astype(jnp.float32),
         x_ref[...].astype(jnp.float32).T,
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     o_ref[...] = _epilogue(post_id, s, xb_ref[...].T, qb_ref[...], c0)
@@ -64,6 +65,7 @@ def _kernel_tiled_k(q_ref, x_ref, qb_ref, xb_ref, o_ref, acc_ref, *, post_id: in
     acc_ref[...] += jnp.dot(
         q_ref[...].astype(jnp.float32),
         x_ref[...].astype(jnp.float32).T,
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 

@@ -60,6 +60,25 @@ from repro.core import (ANNIndex, RetrievalSpec, dispatch_cache_size,
                         get_distance, knn_scan, recall_at_k)
 from repro.core.metrics import speedup_model
 from repro.data.synthetic import lda_like_histograms, split_queries
+from repro.launch.mesh import make_auto_mesh
+
+
+# the checkout's own compile cache: a fixed path, so a later run (or a
+# later process of the same run) finds what an earlier one compiled
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def init_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads it
+    itself); otherwise the cache goes to ``CACHE_DIR`` in the checkout.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
 
 
 # ---------------------------------------------------------------------------
@@ -347,7 +366,7 @@ def build_and_serve(*, spec: RetrievalSpec | None = None,
                     cont_frontier: int = 12, adaptive_frontier: bool = False,
                     utilization: float = 0.4, slo_ms: float | None = None,
                     tenants: int = 1, priority_mix=None, ladder_source=None,
-                    verbose: bool = True):
+                    seed: int = 0, verbose: bool = True):
     if spec is None:
         spec = RetrievalSpec(
             distance=distance, build_policy=index_sym, builder=builder,
@@ -363,7 +382,7 @@ def build_and_serve(*, spec: RetrievalSpec | None = None,
         engine, frontier = spec.engine, spec.frontier
         slots, cont_frontier = spec.slots, spec.sched_frontier
         adaptive_frontier, capacity = spec.adaptive, spec.capacity
-    key = jax.random.PRNGKey(0)
+    key = jax.random.PRNGKey(seed)
     pool_n = churn_rounds * churn_insert
     data = lda_like_histograms(key, n_db + n_queries + pool_n, dim)
     Q, rest = split_queries(data, n_queries, jax.random.fold_in(key, 1))
@@ -389,10 +408,12 @@ def build_and_serve(*, spec: RetrievalSpec | None = None,
     # warm the jit cache on every batch shape served (full batches plus a
     # possible ragged tail) so latency percentiles reflect steady state,
     # not compilation
+    t0 = time.time()
     jax.block_until_ready(search(Q[:batch])[0])
     tail = n_queries % batch
     if tail:
         jax.block_until_ready(search(Q[:tail])[0])
+    compile_s = time.time() - t0
 
     # ground truth for quality accounting
     _, true_ids = knn_scan(dist, Q, X, k)
@@ -413,6 +434,7 @@ def build_and_serve(*, spec: RetrievalSpec | None = None,
     recall = recall_at_k(np.concatenate(all_ids), np.asarray(true_ids))
     stats = {
         "build_s": round(build_s, 2),
+        "compile_s": round(compile_s, 2),
         "engine": engine,
         "served": served,
         "recall@k": round(recall, 4),
@@ -522,7 +544,7 @@ def build_and_serve_sharded(*, distance: str = "kl", n_db: int = 4096,
                             shards: int = 4, steps_per_sync: int = 1,
                             drop_shards: int = 0, NN: int = 15,
                             nnd_iters: int = 8, compare_replicated: bool = True,
-                            verbose: bool = True):
+                            seed: int = 0, verbose: bool = True):
     """Scatter-gather serving: the slot scheduler over a SHARDED corpus.
 
     Each of ``shards`` devices owns ``n_db / shards`` rows (padded when not
@@ -543,10 +565,9 @@ def build_and_serve_sharded(*, distance: str = "kl", n_db: int = 4096,
         raise RuntimeError(
             f"--shards {shards} needs {shards} devices, found "
             f"{len(jax.devices())}; on CPU re-run with XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={shards} (the driver "
-            f"sets it automatically when the backend is not yet initialised)")
-    mesh = jax.make_mesh((shards,), ("data",))
-    key = jax.random.PRNGKey(0)
+            f"--xla_force_host_platform_device_count={shards}")
+    mesh = make_auto_mesh((shards,), ("data",))
+    key = jax.random.PRNGKey(seed)
     data = lda_like_histograms(key, n_db + n_queries, dim)
     Q, X = split_queries(data, n_queries, jax.random.fold_in(key, 1))
     X = X[:n_db]
@@ -579,6 +600,11 @@ def build_and_serve_sharded(*, distance: str = "kl", n_db: int = 4096,
         # the zero-recompile contract, made observable
         "step_executables": dispatch_cache_size(sched._step),
         "admit_executables": dispatch_cache_size(sched._admit),
+        # distinct devices holding a shard of the corpus reps (== shards
+        # when each shard sits on its own device)
+        "shard_devices": len({
+            sh.device for sh in
+            jax.tree.leaves(sched._consts)[0].addressable_shards}),
     }
     if compare_replicated:
         idx = ANNIndex.build(X, dist, builder="nndescent", NN=NN,
@@ -668,8 +694,10 @@ def main(argv=None):
     ap.add_argument("--shards", type=int, default=0,
                     help="serve scatter-gather from N corpus shards through "
                          "the sharded slot scheduler (one device per shard; "
-                         "on CPU the driver forces N host devices via "
-                         "XLA_FLAGS before the backend initialises)")
+                         "on CPU set XLA_FLAGS=--xla_force_host_platform_"
+                         "device_count=N in the environment)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the generated corpus and queries")
     ap.add_argument("--drop-shards", type=int, default=0,
                     help="freeze the last s shards at admission (bounded-"
                          "staleness straggler model, sharded path)")
@@ -685,15 +713,11 @@ def main(argv=None):
         if bad:
             ap.error(f"--shards is its own serving path; incompatible "
                      f"with {bad}")
-        # must happen before ANY backend touch: the forced device count is
-        # read once, at platform initialisation
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.shards}")
+        init_compile_cache()
         return build_and_serve_sharded(
             n_db=args.n_db, dim=args.dim, n_queries=args.queries,
             shards=args.shards, drop_shards=args.drop_shards,
-            steps_per_sync=args.steps_per_sync,
+            steps_per_sync=args.steps_per_sync, seed=args.seed,
             **{k: v for k, v in [("distance", args.distance),
                                  ("ef_search", args.ef_search),
                                  ("slots", args.slots)] if v is not None})
@@ -735,8 +759,9 @@ def main(argv=None):
         if isinstance(doc, dict) and "frontier" in doc:
             # a tuned artifact's Pareto frontier feeds the demotion ladder
             ladder_source = doc
+    init_compile_cache()
     return build_and_serve(
-        spec=spec,
+        spec=spec, seed=args.seed,
         n_db=args.n_db, dim=args.dim, n_queries=args.queries,
         batch=args.batch, churn_rounds=args.churn_rounds,
         churn_insert=args.churn_insert, churn_delete=args.churn_delete,

@@ -21,7 +21,6 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding.api import current_mesh
@@ -99,11 +98,11 @@ def embedding_lookup(table, ids, offsets, *, row_axes=("model", "data")):
         return jax.lax.psum(emb, axes)
 
     out_spec = P((axes[-1],), None, None) if use_scatter else P(None, None, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(None, None)),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )(table, flat)
 
 

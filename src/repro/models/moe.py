@@ -231,7 +231,6 @@ def moe_ffn(h, lp, cfg: LMConfig):
 
 def _moe_ffn_ep(h, lp, cfg: LMConfig, mesh):
     """Expert-parallel MoE under shard_map (see moe_ffn docstring)."""
-    from jax.experimental.shard_map import shard_map
 
     m = cfg.moe
     B, T, d = h.shape
@@ -318,11 +317,11 @@ def _moe_ffn_ep(h, lp, cfg: LMConfig, mesh):
         in_specs += [P(fsdp, "model"), P(fsdp, "model"), P("model", fsdp)]
         args += [lp["sh_gate"], lp["sh_up"], lp["sh_down"]]
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(*args)
     return out, aux.astype(jnp.float32)
 

@@ -331,7 +331,6 @@ def _sp_decode_attention(q, k_cache, v_cache, length, k_new, v_new, window,
     stay exact across shards.  ``seq_axes`` may span multiple mesh axes
     (long_500k shards 512k positions over data x model); ``dp`` axes shard
     the batch dim (empty tuple for batch=1 cells)."""
-    from jax.experimental.shard_map import shard_map
 
     seq_axes = tuple(seq_axes)
     if dp is None:
@@ -366,11 +365,11 @@ def _sp_decode_attention(q, k_cache, v_cache, length, k_new, v_new, window,
         return num / jnp.maximum(den[..., None], 1e-30), kc, vc
 
     spec_kv = P(dp, seq_axes, None, None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(dp, None, None), spec_kv, spec_kv, P(dp),
                   P(dp, None, None, None), P(dp, None, None, None), P()),
         out_specs=(P(dp, None, None), spec_kv, spec_kv),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, length, k_new, v_new, window)

@@ -35,7 +35,6 @@ def sharded_xent(hidden, head, labels, mesh, *, tp_axis: str = "model",
     all-gathered (B, T, V) logits - a 427 GiB/device temp on the dry-run
     (EXPERIMENTS.md SSPerf, hypothesis P1).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, T, d = hidden.shape
@@ -75,11 +74,11 @@ def sharded_xent(hidden, head, labels, mesh, *, tp_axis: str = "model",
             total = jax.lax.psum(total, dp)
         return total
 
-    total = shard_map(
+    total = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, tp_axis), P(dp, None)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(hidden, head, labels)
     return total / (B * T)
 

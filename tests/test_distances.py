@@ -137,3 +137,40 @@ def test_property_decomposition_random_shapes(d, seed, name):
     M = dist.matrix(U, V)
     want = jax.vmap(lambda u: jax.vmap(lambda v: dist.pairwise(u, v))(V))(U)
     np.testing.assert_allclose(M, want, rtol=5e-4, atol=5e-5)
+
+
+def _dot_precisions(lowered):
+    import re
+
+    lines = [ln for ln in lowered.as_text().splitlines() if "dot_general" in ln]
+    assert lines, "no matmul in the lowered program"
+    return [re.search(r"precision = \[(\w+), (\w+)\]", ln).groups() for ln in lines]
+
+
+def _rows_and_query(dist, n=12, m=16):
+    X = _hists(2, n, m)
+    consts = dist.prep_scan(X)
+    return consts, dist.prep_query(_hists(3, 1, m)[0])
+
+
+@pytest.mark.parametrize("name", ["kl", "itakura_saito", "renyi_2", "l2", "negdot"])
+def test_ranking_matmuls_pin_highest_precision(name):
+    """The exact scan, the beam's score and the kernel oracles run their
+    matmuls at HIGHEST: XLA's default on a TPU passes f32 through bf16, and
+    the reference every recall divides by would rank wrongly there."""
+    from repro.core.brute_force import knn_scan
+    from repro.kernels import ref
+
+    dist = D.get_distance(name)
+    Q, X = _hists(4, 3, 16), _hists(5, 40, 16)
+    lowered = [
+        knn_scan.lower(dist, Q, X, 5, chunk=16),
+        jax.jit(dist.score).lower(*_rows_and_query(dist)),
+        jax.jit(lambda q, x: dist.query_matrix(q, x, mode="right")).lower(Q, X),
+        jax.jit(ref.distance_matrix_ref, static_argnums=(4, 5)).lower(
+            Q, X, Q[:, 0], X[:, 0], dist.post_id, dist.c0),
+        jax.jit(ref.gather_scores_ref, static_argnums=(5, 6)).lower(
+            jnp.zeros((3, 4), jnp.int32), Q, X, Q[:, 0], X[:, 0], dist.post_id, dist.c0),
+    ]
+    for lo in lowered:
+        assert set(_dot_precisions(lo)) == {("HIGHEST", "HIGHEST")}

@@ -14,7 +14,7 @@ from repro.core.distances import get_distance
 from repro.data.synthetic import random_histograms
 from repro.kernels import ref as kref
 from repro.kernels.distance_matrix import distance_matrix
-from repro.kernels.gather_topk import gather_scores
+from repro.kernels.frontier_gather import frontier_scores, row_view
 from repro.kernels.ops import beam_gather_scores, query_distance_matrix
 
 DISTS = ["kl", "itakura_saito", "renyi_0.25", "renyi_2", "l2", "negdot"]
@@ -72,9 +72,26 @@ def test_gather_scores_kernel_vs_ref(name):
     B, M, n, m = 6, 10, 40, 16
     q_rep, x_rep, q_bias, x_bias, _, _ = _reps(dist, B, n, m, seed=3)
     ids = jax.random.randint(jax.random.PRNGKey(9), (B, M), -1, n)
-    got = gather_scores(ids, q_rep, x_rep, q_bias, x_bias, dist.post_id, dist.c0,
-                        interpret=True)
+    got = frontier_scores(ids, q_rep, q_bias, row_view(x_rep), x_bias, dist.post_id,
+                          dist.c0, interpret=True)
     want = kref.gather_scores_ref(ids, q_rep, x_rep, q_bias, x_bias, dist.post_id, dist.c0)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert bool(jnp.all(jnp.isinf(got[ids < 0])))
+
+
+@pytest.mark.parametrize("name", ["kl", "renyi_2", "l2"])
+@pytest.mark.parametrize("B,R,m", [(9, 30, 8), (3, 7, 128), (17, 12, 200), (2, 5, 256)])
+def test_frontier_kernel_row_views(name, B, R, m):
+    """Every corpus row view the kernel DMAs from: lane-padded (m' < 128),
+    as-is (m' = 128) and split into K rows (m' > 128, ragged or not), with
+    B padded up to whole 8-query grid steps."""
+    dist = get_distance(name)
+    q_rep, x_rep, q_bias, x_bias, _, _ = _reps(dist, B, 23, m, seed=m)
+    ids = jax.random.randint(jax.random.PRNGKey(B), (B, R), -1, 23)
+    got = frontier_scores(ids, q_rep, q_bias, row_view(x_rep), x_bias, dist.post_id,
+                          dist.c0, interpret=True)
+    want = kref.gather_scores_ref(ids, q_rep, x_rep, q_bias, x_bias, dist.post_id, dist.c0)
+    assert got.shape == (B, R)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     assert bool(jnp.all(jnp.isinf(got[ids < 0])))
 

@@ -142,6 +142,28 @@ def test_nndescent_improves_over_random(data):
     assert r >= 0.6, f"graph recall={r}"
 
 
+@pytest.mark.parametrize("join_rows", [64, 113])
+def test_nndescent_blocked_join_is_exact(data, join_rows, monkeypatch):
+    """Joining the nodes block by block (a ragged tail included) builds the
+    same graph as one whole-corpus join: every block reads the previous
+    round's lists."""
+    from repro.core import nndescent
+
+    _, X = data
+    X = X[:300]
+    dist = get_distance("kl")
+    key = jax.random.PRNGKey(5)
+    whole, _ = build_nndescent(dist, X, key, K=8, iters=3)
+    # the block size is read when build_nndescent traces
+    monkeypatch.setattr(nndescent, "_JOIN_ROWS", join_rows)
+    jax.clear_caches()
+    try:
+        blocked, _ = build_nndescent(dist, X, key, K=8, iters=3)
+    finally:
+        jax.clear_caches()
+    np.testing.assert_array_equal(np.asarray(blocked), np.asarray(whole))
+
+
 def test_beam_search_finds_entry_neighbors(data):
     _, X = data
     dist = get_distance("kl")

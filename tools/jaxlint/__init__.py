@@ -191,7 +191,8 @@ class _FileLint:
 
         jit_names = ("jax.jit", "jax.numpy.jit")
         for node in ast.walk(self.tree):
-            if isinstance(node, ast.Call) and self._is(node.func, "jax.experimental.shard_map.shard_map", "shard_map"):
+            if isinstance(node, ast.Call) and self._is(
+                    node.func, "jax.shard_map", "jax.experimental.shard_map.shard_map", "shard_map"):
                 self.uses_shard_map = True
             if isinstance(node, ast.Call) and self._is(node.func, *jit_names):
                 target = self._assign_target(node)
