@@ -1,0 +1,30 @@
+"""Slot scheduler dispatch (``core/scheduler.py`` ``tick``).
+
+Mean time, in ms, per tick of the spans that hand work to the device:
+``repro.tick.put`` (the admitted queries' host-to-device copies),
+``repro.tick.admit``, ``repro.tick.step`` and ``repro.tick.release``.
+Read from the program's tick log (``repro.core.telemetry``), over the
+ticks called with ``now`` before the profiler started (the ticks
+``tick_ms`` counts).  Stream cells only; nothing is read where the program
+keeps no tick log, where the log is empty, or where it overwrote the
+window's first ticks.
+"""
+
+import numpy as np
+
+
+def read(run):
+    if run["kind"] != "open_loop":
+        return None
+    try:
+        from repro.core import telemetry
+    except ImportError:
+        return None
+    log = telemetry.latest()
+    if log is None or log.dropped:
+        return None
+    rows = log.rows(until=run["rec"]["host_until"])
+    if not len(rows["now"]):
+        return None
+    dispatch = rows["put"] + rows["admit"] + rows["step"] + rows["release"]
+    return 1e3 * float(np.mean(dispatch))

@@ -205,85 +205,92 @@ def beam_step(
     rows_b = jnp.arange(B)[:, None]
     M = neighbors.shape[1]
 
-    # -- per-query convergence masking (NMSLIB efSearch semantics)
-    cand = jnp.where(st.expanded, INF, st.beam_d)  # (B, ef)
-    best = jnp.min(cand, axis=1)
-    if ef_active is None:
-        worst = st.beam_d[:, -1]
-    else:
-        wi = jnp.clip(ef_active - 1, 0, ef - 1)[:, None]
-        worst = jnp.take_along_axis(st.beam_d, wi, axis=1)[:, 0]
-    done = st.done | ~((best <= worst) & jnp.isfinite(best)) | (st.hops >= max_steps)
-    active = ~done
+    # named scopes label the step's device ops by part (op metadata only)
+    with jax.named_scope("expand"):
+        # -- per-query convergence masking (NMSLIB efSearch semantics)
+        cand = jnp.where(st.expanded, INF, st.beam_d)  # (B, ef)
+        best = jnp.min(cand, axis=1)
+        if ef_active is None:
+            worst = st.beam_d[:, -1]
+        else:
+            wi = jnp.clip(ef_active - 1, 0, ef - 1)[:, None]
+            worst = jnp.take_along_axis(st.beam_d, wi, axis=1)[:, 0]
+        done = st.done | ~((best <= worst) & jnp.isfinite(best)) | (st.hops >= max_steps)
+        active = ~done
 
-    # -- pop the top-T unexpanded candidates of each active query,
-    # gated to the termination radius (a candidate farther than the
-    # current worst beam member would never be expanded sequentially)
-    neg_d, slots = jax.lax.top_k(-cand, T)  # (B, T), best-first
-    ok = jnp.isfinite(neg_d) & (-neg_d <= worst[:, None]) & active[:, None]  # (B, T)
-    if t_active is not None:
-        ok &= jnp.arange(T)[None, :] < jnp.minimum(t_active, T)[:, None]
-    nodes = jnp.take_along_axis(st.beam_i, slots, axis=1)
-    expanded = st.expanded.at[rows_b, slots].max(ok)
+        # -- pop the top-T unexpanded candidates of each active query,
+        # gated to the termination radius (a candidate farther than the
+        # current worst beam member would never be expanded sequentially)
+        neg_d, slots = jax.lax.top_k(-cand, T)  # (B, T), best-first
+        ok = jnp.isfinite(neg_d) & (-neg_d <= worst[:, None]) & active[:, None]  # (B, T)
+        if t_active is not None:
+            ok &= jnp.arange(T)[None, :] < jnp.minimum(t_active, T)[:, None]
+        nodes = jnp.take_along_axis(st.beam_i, slots, axis=1)
+        expanded = st.expanded.at[rows_b, slots].max(ok)
 
-    # -- gather + score the (B, T*M) neighbor frontier in one fused call
-    safe_nodes = jnp.where(ok, nodes, 0)
-    nbrs = neighbors[safe_nodes].reshape(B, T * M)
-    ok_r = jnp.repeat(ok, M, axis=1)  # (B, T*M), block-aligned
-    safe = jnp.where(nbrs >= 0, nbrs, 0)
-    words = jnp.take_along_axis(st.visited, safe // 32, axis=1)
-    unvisited = ((words >> (safe % 32).astype(jnp.uint32)) & 1) == 0
-    valid = (nbrs >= 0) & unvisited & ok_r
-    d = jnp.where(valid, score_rows(safe).astype(jnp.float32), INF)
+    with jax.named_scope("score"):
+        # -- gather + score the (B, T*M) neighbor frontier in one fused call
+        safe_nodes = jnp.where(ok, nodes, 0)
+        nbrs = neighbors[safe_nodes].reshape(B, T * M)
+        ok_r = jnp.repeat(ok, M, axis=1)  # (B, T*M), block-aligned
+        safe = jnp.where(nbrs >= 0, nbrs, 0)
+        words = jnp.take_along_axis(st.visited, safe // 32, axis=1)
+        unvisited = ((words >> (safe % 32).astype(jnp.uint32)) & 1) == 0
+        valid = (nbrs >= 0) & unvisited & ok_r
+        d = jnp.where(valid, score_rows(safe).astype(jnp.float32), INF)
 
-    # -- compact to the C best candidates (top_k breaks distance ties by
-    # position, i.e. exactly like a stable sort of the frontier)
-    neg_kept, kidx = jax.lax.top_k(-d, C)
-    kept_d = -neg_kept
-    kept_i = jnp.take_along_axis(nbrs, kidx, axis=1)
-    kept_ok = jnp.take_along_axis(valid, kidx, axis=1)
-    # two expanded nodes may share a neighbor (and adjacency rows may
-    # repeat ids): find later duplicates on the compacted block (O(C^2))
-    later = jnp.arange(C)[:, None] > jnp.arange(C)[None, :]  # [j, s]
-    dup = jnp.any(
-        (kept_i[:, :, None] == kept_i[:, None, :]) & later[None] & kept_ok[:, None, :],
-        axis=2,
-    )
-    if T > 1:
-        # keep the first (best) occurrence in the beam, void the rest,
-        # then restore sortedness (top_k ties-by-index keeps the order
-        # of the surviving entries) — the merge needs an ascending block
-        kept_d = jnp.where(dup, INF, kept_d)
-        kept_ok = kept_ok & ~dup
-        neg_srt, ridx = jax.lax.top_k(-kept_d, C)
-        kept_d = -neg_srt
-        kept_i = jnp.take_along_axis(kept_i, ridx, axis=1)
-        kept_ok = jnp.take_along_axis(kept_ok, ridx, axis=1)
-        mark = kept_ok
-    else:
-        mark = kept_ok & ~dup
-    # mark kept candidates visited: per-row-unique (word, bit) updates,
-    # so a scatter-add of fresh bits then a word-wise OR is exact
-    safe_kept = jnp.where(mark, kept_i, 0)
-    bits = jnp.where(mark, jnp.uint32(1) << (safe_kept % 32).astype(jnp.uint32), 0)
-    step_mask = jnp.zeros_like(st.visited).at[rows_b, safe_kept // 32].add(bits)
-    visited = st.visited | step_mask
+    with jax.named_scope("merge"):
+        # -- compact to the C best candidates (top_k breaks distance ties by
+        # position, i.e. exactly like a stable sort of the frontier)
+        neg_kept, kidx = jax.lax.top_k(-d, C)
+        kept_d = -neg_kept
+        kept_i = jnp.take_along_axis(nbrs, kidx, axis=1)
+        kept_ok = jnp.take_along_axis(valid, kidx, axis=1)
+        # two expanded nodes may share a neighbor (and adjacency rows may
+        # repeat ids): find later duplicates on the compacted block (O(C^2))
+        later = jnp.arange(C)[:, None] > jnp.arange(C)[None, :]  # [j, s]
+        dup = jnp.any(
+            (kept_i[:, :, None] == kept_i[:, None, :]) & later[None] & kept_ok[:, None, :],
+            axis=2,
+        )
+        if T > 1:
+            # keep the first (best) occurrence in the beam, void the rest,
+            # then restore sortedness (top_k ties-by-index keeps the order
+            # of the surviving entries) — the merge needs an ascending block
+            kept_d = jnp.where(dup, INF, kept_d)
+            kept_ok = kept_ok & ~dup
+            neg_srt, ridx = jax.lax.top_k(-kept_d, C)
+            kept_d = -neg_srt
+            kept_i = jnp.take_along_axis(kept_i, ridx, axis=1)
+            kept_ok = jnp.take_along_axis(kept_ok, ridx, axis=1)
+            mark = kept_ok
+        else:
+            mark = kept_ok & ~dup
 
-    # -- bitonic merge of the sorted beam with the sorted candidates:
-    # lexicographic (distance, position) keys reproduce the stable
-    # argsort of [beam | candidates] that the reference engine computes.
-    beam_d, beam_i, beam_e = _bitonic_merge(
-        (st.beam_d, st.beam_i, expanded), (kept_d, kept_i, ~kept_ok), ef
-    )
-    if ef_active is not None:
-        # void the beam tail beyond each query's effective width: the first
-        # ef_active entries of the stable merge are exactly what a merge
-        # into an ef_active-wide beam would keep, so voiding the rest keeps
-        # the narrow-engine equivalence exact
-        off = jnp.arange(ef, dtype=jnp.int32)[None, :] >= ef_active[:, None]
-        beam_d = jnp.where(off, INF, beam_d)
-        beam_i = jnp.where(off, -1, beam_i)
-        beam_e = beam_e | off
+    with jax.named_scope("visited"):
+        # mark kept candidates visited: per-row-unique (word, bit) updates,
+        # so a scatter-add of fresh bits then a word-wise OR is exact
+        safe_kept = jnp.where(mark, kept_i, 0)
+        bits = jnp.where(mark, jnp.uint32(1) << (safe_kept % 32).astype(jnp.uint32), 0)
+        step_mask = jnp.zeros_like(st.visited).at[rows_b, safe_kept // 32].add(bits)
+        visited = st.visited | step_mask
+
+    with jax.named_scope("merge"):
+        # -- bitonic merge of the sorted beam with the sorted candidates:
+        # lexicographic (distance, position) keys reproduce the stable
+        # argsort of [beam | candidates] that the reference engine computes.
+        beam_d, beam_i, beam_e = _bitonic_merge(
+            (st.beam_d, st.beam_i, expanded), (kept_d, kept_i, ~kept_ok), ef
+        )
+        if ef_active is not None:
+            # void the beam tail beyond each query's effective width: the
+            # first ef_active entries of the stable merge are exactly what a
+            # merge into an ef_active-wide beam would keep, so voiding the
+            # rest keeps the narrow-engine equivalence exact
+            off = jnp.arange(ef, dtype=jnp.int32)[None, :] >= ef_active[:, None]
+            beam_d = jnp.where(off, INF, beam_d)
+            beam_i = jnp.where(off, -1, beam_i)
+            beam_e = beam_e | off
     return BatchBeamState(
         beam_d,
         beam_i,

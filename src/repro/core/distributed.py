@@ -369,7 +369,7 @@ class ShardedSlotScheduler(SchedulerHost):
         self.rungs = [Rung(ef=self.ef, name="full")]
         self.slo_s = None if slo_ms is None else float(slo_ms) / 1e3
         self._background = background_fn
-        self._init_host_queue(tenant_weights)
+        self._init_host(tenant_weights)
         self.reset()  # host-built template state for _build_jits' spec trees
         self._build_jits()
         self.reset()  # re-commit through _init: canonical jit-output shardings
@@ -409,6 +409,7 @@ class ShardedSlotScheduler(SchedulerHost):
         consts_spec = self._specs(self._consts, sharded=True)
         nbrs_spec = P(db_axes, None)
 
+        @jax.named_scope("admit")
         def admit(core_g, qc, glob_d, glob_i, Q_new, write, consts):
             # core leaves arrive as (1, S, ...): each shard's slice of the
             # leading shard axis — squeeze for the slot-level state machine
@@ -433,6 +434,7 @@ class ShardedSlotScheduler(SchedulerHost):
             glob_i = jnp.where(write[:, None], -1, glob_i)
             return (jax.tree.map(lambda a: a[None], core), qc, glob_d, glob_i)
 
+        @jax.named_scope("step")
         def step(core_g, qc, consts, neighbors):
             core = jax.tree.map(lambda a: a[0], core_g)
             shard = jax.lax.axis_index(db_axes)
@@ -539,66 +541,85 @@ class ShardedSlotScheduler(SchedulerHost):
                 glob_d=jnp.full((S, k), INF, jnp.float32),
                 glob_i=jnp.full((S, k), -1, jnp.int32),
             )
-        self._clear_host_queue()
+        self._clear_host()
         self._slot_rid = np.full((S,), -1, np.int64)
-        # rid -> (arrival, admit time, tenant, priority)
+        # rid -> (arrival, admit time, tenant, priority, admit tick)
         self._meta: dict[int, tuple] = {}
 
     # -------------------------------------------------------------- serving
 
-    def tick(self, now: float = 0.0) -> list[SlotResult]:
+    def _tick(self, now: float, t) -> list[SlotResult]:
         """Admit pending requests into free slots (DRR across tenants), run
         ``steps_per_sync`` lock-steps on every shard, exchange + merge at
-        the sync point, retire every globally converged slot."""
+        the sync point, retire every globally converged slot.  ``t`` is the
+        tick's open log row (the same spans as ``SlotScheduler``'s tick;
+        admission writes over a retired slot, so there is no release)."""
         st = self.state
+        counters = self.log.counters
         free = np.flatnonzero(self._slot_rid < 0)
         if len(free) and self._n_pending:
-            Q_new = np.full((self.S, self.dim), 1.0 / self.dim, np.float32)
-            write = np.zeros((self.S,), bool)
-            for fi, req in enumerate(self._drr_select(len(free))):
-                s = free[fi]
-                Q_new[s] = req.q
-                write[s] = True
-                self._slot_rid[s] = req.rid
-                self._meta[req.rid] = (req.t_arrival, now, req.tenant,
-                                       req.priority)
-            if write.any():
-                core, qc, glob_d, glob_i = self._admit(
-                    st.core, st.qc, st.glob_d, st.glob_i,
-                    jnp.asarray(Q_new, self._dtype), jnp.asarray(write),
-                    self._consts,
-                )
+            with t.span("select"):
+                Q_new = np.full((self.S, self.dim), 1.0 / self.dim,
+                                np.float32)
+                write = np.zeros((self.S,), bool)
+                reqs = self._drr_select(len(free))
+                for s, req in zip(free, reqs):
+                    Q_new[s] = req.q
+                    write[s] = True
+                    self._slot_rid[s] = req.rid
+                    self._meta[req.rid] = (req.t_arrival, now, req.tenant,
+                                           req.priority, t.index)
+                counters["admitted"] += len(reqs)
+            if reqs:
+                with t.span("put"):
+                    Q_dev = jnp.asarray(Q_new, self._dtype)
+                    write_dev = jnp.asarray(write)
+                with t.span("admit"):
+                    core, qc, glob_d, glob_i = self._admit(
+                        st.core, st.qc, st.glob_d, st.glob_i, Q_dev,
+                        write_dev, self._consts,
+                    )
                 st = ShardSlotState(core, qc, glob_d, glob_i)
         if (self._background is not None and not self._n_pending
                 and (self._slot_rid < 0).any()):
-            self._background()
-        if not (self._slot_rid >= 0).any():
+            with t.span("background"):
+                self._background()
+        occupied = int((self._slot_rid >= 0).sum())
+        t.set("occupied", occupied)
+        if not occupied:
             self.state = st
             return []
 
-        core, glob_d, glob_i, done_g, evals_g, hops_g = self._step(
-            st.core, st.qc, self._consts, self._neighbors)
+        with t.span("step"):
+            core, glob_d, glob_i, done_g, evals_g, hops_g = self._step(
+                st.core, st.qc, self._consts, self._neighbors)
         self.state = ShardSlotState(core, st.qc, glob_d, glob_i)
 
-        done = np.asarray(done_g)  # syncs the step
+        with t.span("sync"):
+            done = np.asarray(done_g)  # syncs the step
         finished = done & (self._slot_rid >= 0)
         if not finished.any():
             return []
         # fixed-shape device reads (full S rows, host-side row select), so
         # retiring any number of slots reuses the same executables
-        idx = np.flatnonzero(finished)
-        d = np.asarray(glob_d)[idx]
-        ids = np.asarray(glob_i).astype(np.int64)[idx]
-        evals = np.asarray(evals_g)[idx]
-        hops = np.asarray(hops_g)[idx]
+        with t.span("retire_read"):
+            idx = np.flatnonzero(finished)
+            d = np.asarray(glob_d)[idx]
+            ids = np.asarray(glob_i).astype(np.int64)[idx]
+            evals = np.asarray(evals_g)[idx]
+            hops = np.asarray(hops_g)[idx]
         out = []
-        for j, s in enumerate(idx):
-            rid = int(self._slot_rid[s])
-            t_arr, t_adm, tenant, priority = self._meta.pop(
-                rid, (0.0, 0.0, 0, 0))
-            out.append(SlotResult(rid=rid, dists=d[j], ids=ids[j],
-                                  n_evals=int(evals[j]), hops=int(hops[j]),
-                                  t_arrival=t_arr, t_admit=t_adm,
-                                  tenant=tenant, priority=priority))
-            self._slot_rid[s] = -1
+        with t.span("retire"):
+            for j, s in enumerate(idx):
+                rid = int(self._slot_rid[s])
+                t_arr, t_adm, tenant, priority, t_idx = self._meta.pop(
+                    rid, (0.0, 0.0, 0, 0, t.index))
+                counters["held_ticks"] += t.index - t_idx + 1
+                out.append(SlotResult(rid=rid, dists=d[j], ids=ids[j],
+                                      n_evals=int(evals[j]),
+                                      hops=int(hops[j]), t_arrival=t_arr,
+                                      t_admit=t_adm, tenant=tenant,
+                                      priority=priority))
+                self._slot_rid[s] = -1
+            counters["retired"] += len(idx)
         return out

@@ -85,6 +85,7 @@ from .batched_beam import (
     seed_beams,
 )
 from .distances import Distance
+from .telemetry import TickLog
 
 INF = jnp.inf
 
@@ -253,6 +254,10 @@ class AdmissionController:
     ``mean * margin`` converts those admitted-but-doomed requests into
     earlier demotions/sheds, which is what keeps goodput near peak under
     deep overload.
+
+    Each demotion and shed is counted in ``counters`` (``"demoted"``,
+    ``"shed"``), a ``collections.Counter`` of the controller's own until a
+    scheduler hands it its tick log's counters.
     """
 
     def __init__(self, rungs: list[Rung], slots: int, *, shed: bool = True,
@@ -265,8 +270,15 @@ class AdmissionController:
         self.margin = float(margin)
         self.estimator = ServiceRateEstimator(slots, alpha=alpha, prior=prior,
                                               n_rungs=len(self.rungs))
-        self.n_demoted = 0
-        self.n_shed = 0
+        self.counters = collections.Counter()
+
+    @property
+    def n_demoted(self) -> int:
+        return self.counters["demoted"]
+
+    @property
+    def n_shed(self) -> int:
+        return self.counters["shed"]
 
     def decide(self, *, elapsed: float, slo_s: Optional[float],
                base_level: int = 0, queue_wait: float = 0.0) -> Optional[int]:
@@ -280,13 +292,13 @@ class AdmissionController:
             planned = self.estimator.service_s(lvl, self.rungs[lvl].scale)
             if planned * self.margin <= remaining:
                 if lvl > base:
-                    self.n_demoted += 1
+                    self.counters["demoted"] += 1
                 return lvl
         if self.shed:
-            self.n_shed += 1
+            self.counters["shed"] += 1
             return None
         if last > base:
-            self.n_demoted += 1
+            self.counters["demoted"] += 1
         return last
 
 
@@ -294,18 +306,21 @@ class SchedulerHost:
     """Host-side serving machinery shared by every slot scheduler.
 
     Owns the pending-request queues (per-tenant DRR with strict priority
-    within a tenant), request submission, and the drain / warmup /
-    ``run_stream`` drivers.  Subclasses — the single-device
-    ``SlotScheduler`` and the scatter-gather
-    ``repro.core.distributed.ShardedSlotScheduler`` — provide the device
-    state plus ``tick(now)`` / ``reset()``, the ``dim`` / ``rungs`` /
-    ``slo_s`` attributes, the host-side ``_slot_rid`` occupancy array and
-    an optional ``_background`` idle hook; everything here is
-    device-layout agnostic.
+    within a tenant), request submission, the tick log
+    (``repro.core.telemetry``: ``tick`` runs the subclass's ``_tick``
+    inside one logged row) and the drain / warmup / ``run_stream``
+    drivers.  Subclasses — the single-device ``SlotScheduler`` and the
+    scatter-gather ``repro.core.distributed.ShardedSlotScheduler`` —
+    provide the device state plus ``_tick(now, t)`` / ``reset()``, the
+    ``dim`` / ``rungs`` / ``slo_s`` attributes, the host-side ``_slot_rid``
+    occupancy array and an optional ``_background`` idle hook; everything
+    here is device-layout agnostic.
     """
 
-    def _init_host_queue(self, tenant_weights=None):
-        """Validate tenant weights and create the (empty) queue state."""
+    def _init_host(self, tenant_weights=None):
+        """Validate tenant weights; create the (empty) queue state and the
+        tick log."""
+        self.log = TickLog()
         self._rid_gen = itertools.count()
         self._weights = {int(t): float(w)
                          for t, w in (tenant_weights or {}).items()}
@@ -317,11 +332,19 @@ class SchedulerHost:
         self._deficit: dict[int, float] = {}
         self._n_pending = 0
 
-    def _clear_host_queue(self):
+    def _clear_host(self):
         self._queues.clear()
         self._tenant_order.clear()
         self._deficit.clear()
         self._n_pending = 0
+        self.log.reset()
+
+    def tick(self, now: float = 0.0) -> list[SlotResult]:
+        """Serve one tick (the subclass's ``_tick``) as one row of the tick
+        log, inside the ``repro.tick`` span; ``now`` is the caller's clock,
+        echoed into the results' ``t_admit``."""
+        with self.log.tick(now) as t:
+            return self._tick(now, t)
 
     @property
     def n_inflight(self) -> int:
@@ -608,7 +631,9 @@ class SlotScheduler(SchedulerHost):
             rungs, self.S, shed=shed, alpha=service_alpha,
             prior=service_prior, margin=admission_margin)
         self._background = background_fn
-        self._init_host_queue(tenant_weights)
+        self._init_host(tenant_weights)
+        # admission decisions count into the tick log's counters
+        self.admission.counters = self.log.counters
         self._build_jits()
         self.reset()
 
@@ -651,6 +676,9 @@ class SlotScheduler(SchedulerHost):
         patience = self.patience
         qos, any_adaptive = self._qos, self._any_adaptive
 
+        # named scopes label the device ops of each program (op metadata
+        # only: the compiled code is the same)
+        @jax.named_scope("admit")
         def admit(state: SlotState, Q_new, write, consts, rows, entries,
                   alive, ef_new, ad_new):
             qc_new = jax.vmap(dist.prep_query)(Q_new)
@@ -686,6 +714,7 @@ class SlotScheduler(SchedulerHost):
                 adapt=jnp.where(write, ad_new, state.adapt),
             )
 
+        @jax.named_scope("step")
         def step(state: SlotState, neighbors, consts, rows):
             score_rows = self._score_fn(consts, rows, state.qc)
             core, t_cur, stall, worst = (state.core, state.t_cur, state.stall,
@@ -713,6 +742,7 @@ class SlotScheduler(SchedulerHost):
             return state._replace(core=core, t_cur=t_cur, stall=stall,
                                   worst=worst)
 
+        @jax.named_scope("release")
         def release(state: SlotState, freed):
             return state._replace(occupied=state.occupied & ~freed)
 
@@ -749,101 +779,118 @@ class SlotScheduler(SchedulerHost):
             ef_act=jnp.full((S,), self.ef, jnp.int32),
             adapt=jnp.full((S,), self.adaptive, bool),
         )
-        self._clear_host_queue()
         # the learned service-rate estimate survives reset (it describes
-        # the hardware, not the request stream); the per-run QoS counters
-        # do not
-        self.admission.n_demoted = 0
-        self.admission.n_shed = 0
+        # the hardware, not the request stream); the per-run counters of
+        # the tick log do not
+        self._clear_host()
         self._slot_rid = np.full((S,), -1, np.int64)
         self._slot_level = np.zeros((S,), np.int64)
         # raw per-slot query rows, kept host-side for the retire-time rerank
         self._slot_q = np.zeros((S, self.dim), np.float32)
         # rid -> (arrival, admit time, admission epoch, tenant, priority,
-        # rung level)
+        # rung level, admit tick)
         self._meta: dict[int, tuple] = {}
 
     @property
     def qos_stats(self) -> dict:
-        """Per-run admission counters (zeroed by ``reset``)."""
+        """Per-run admission counters (the tick log's, zeroed by ``reset``)
+        and the service-rate estimate."""
         est = self.admission.estimator
         return {
-            "demoted": self.admission.n_demoted,
-            "shed": self.admission.n_shed,
+            "demoted": self.log.counters["demoted"],
+            "shed": self.log.counters["shed"],
             "mean_service_s": est.mean,
             "rate_per_slot": est.rate_per_slot,
         }
 
     # -------------------------------------------------------------- serving
 
-    def tick(self, now: float = 0.0) -> list[SlotResult]:
+    def _tick(self, now: float, t) -> list[SlotResult]:
         """Admit pending requests into free slots (DRR across tenants,
         SLO admission control per request), run ``steps_per_sync``
         lock-steps, retire every converged slot.  Returns retired results
         plus any load-shed responses (``t_done`` left for the caller's
-        clock)."""
+        clock).  ``t`` is the tick's open log row: each part runs in its
+        span, in the order of ``repro.core.telemetry.SPANS``."""
         g = self.graph_fn()
+        counters = self.log.counters
         shed_out: list[SlotResult] = []
         free = np.flatnonzero(self._slot_rid < 0)
         if len(free) and self._n_pending:
-            Q_new = np.full((self.S, self.dim), 1.0 / self.dim, np.float32)
-            write = np.zeros((self.S,), bool)
-            ef_new = np.full((self.S,), self.ef, np.int32)
-            ad_new = np.full((self.S,), self.adaptive, bool)
-            fi = 0
-            # shed decisions free no slot, so keep drawing from the DRR
-            # queues until the free slots are filled or the queues drain
-            while fi < len(free) and self._n_pending:
-                for req in self._drr_select(len(free) - fi):
-                    lvl = req.level
-                    if lvl is None:
-                        lvl = self.admission.decide(
-                            elapsed=now - req.t_arrival, slo_s=req.slo_s,
-                            base_level=min(req.priority, len(self.rungs) - 1),
-                        )
-                    if lvl is None:
-                        # load-shed: answer immediately without burning a
-                        # slot — demotion was already ruled out by decide()
-                        shed_out.append(SlotResult(
-                            rid=req.rid,
-                            dists=np.full((self.k,), np.inf, np.float32),
-                            ids=np.full((self.k,), -1, np.int64),
-                            n_evals=0, hops=0, t_arrival=req.t_arrival,
-                            t_admit=now, tenant=req.tenant,
-                            priority=req.priority, level=-1, shed=True,
-                        ))
-                        continue
-                    rung = self.rungs[lvl]
-                    s = free[fi]
-                    fi += 1
-                    Q_new[s] = req.q
-                    write[s] = True
-                    ef_new[s] = rung.ef
-                    ad_new[s] = rung.adaptive
-                    self._slot_rid[s] = req.rid
-                    self._slot_q[s] = req.q
-                    self._slot_level[s] = lvl
-                    self._meta[req.rid] = (req.t_arrival, now, g.epoch,
-                                           req.tenant, req.priority, lvl)
-            if write.any():
-                self.state = self._admit(
-                    self.state, jnp.asarray(Q_new, self._dtype),
-                    jnp.asarray(write), g.consts,
-                    self._kernel_rows(g.consts), g.entries, g.alive,
-                    jnp.asarray(ef_new), jnp.asarray(ad_new),
-                )
+            with t.span("select"):
+                Q_new = np.full((self.S, self.dim), 1.0 / self.dim,
+                                np.float32)
+                write = np.zeros((self.S,), bool)
+                ef_new = np.full((self.S,), self.ef, np.int32)
+                ad_new = np.full((self.S,), self.adaptive, bool)
+                fi = 0
+                # shed decisions free no slot, so keep drawing from the DRR
+                # queues until the free slots are filled or the queues drain
+                while fi < len(free) and self._n_pending:
+                    for req in self._drr_select(len(free) - fi):
+                        lvl = req.level
+                        if lvl is None:
+                            lvl = self.admission.decide(
+                                elapsed=now - req.t_arrival, slo_s=req.slo_s,
+                                base_level=min(req.priority,
+                                               len(self.rungs) - 1),
+                            )
+                        if lvl is None:
+                            # load-shed: answer immediately without burning
+                            # a slot — demotion was already ruled out by
+                            # decide()
+                            shed_out.append(SlotResult(
+                                rid=req.rid,
+                                dists=np.full((self.k,), np.inf, np.float32),
+                                ids=np.full((self.k,), -1, np.int64),
+                                n_evals=0, hops=0, t_arrival=req.t_arrival,
+                                t_admit=now, tenant=req.tenant,
+                                priority=req.priority, level=-1, shed=True,
+                            ))
+                            continue
+                        rung = self.rungs[lvl]
+                        s = free[fi]
+                        fi += 1
+                        Q_new[s] = req.q
+                        write[s] = True
+                        ef_new[s] = rung.ef
+                        ad_new[s] = rung.adaptive
+                        self._slot_rid[s] = req.rid
+                        self._slot_q[s] = req.q
+                        self._slot_level[s] = lvl
+                        self._meta[req.rid] = (req.t_arrival, now, g.epoch,
+                                               req.tenant, req.priority, lvl,
+                                               t.index)
+                counters["admitted"] += fi
+            if fi:
+                with t.span("put"):
+                    Q_dev = jnp.asarray(Q_new, self._dtype)
+                    write_dev = jnp.asarray(write)
+                    ef_dev = jnp.asarray(ef_new)
+                    ad_dev = jnp.asarray(ad_new)
+                with t.span("admit"):
+                    self.state = self._admit(
+                        self.state, Q_dev, write_dev, g.consts,
+                        self._kernel_rows(g.consts), g.entries, g.alive,
+                        ef_dev, ad_dev,
+                    )
         if (self._background is not None and not self._n_pending
                 and (self._slot_rid < 0).any()):
             # idle capacity this tick: hang one slice of background index
             # maintenance (incremental compaction)
-            self._background()
-        if not (self._slot_rid >= 0).any():
+            with t.span("background"):
+                self._background()
+        occupied = int((self._slot_rid >= 0).sum())
+        t.set("occupied", occupied)
+        if not occupied:
             return shed_out
 
-        self.state = self._step(self.state, g.neighbors, g.consts,
-                                self._kernel_rows(g.consts))
+        with t.span("step"):
+            self.state = self._step(self.state, g.neighbors, g.consts,
+                                    self._kernel_rows(g.consts))
 
-        done = np.asarray(self.state.core.done)  # syncs the step
+        with t.span("sync"):
+            done = np.asarray(self.state.core.done)  # syncs the step
         finished = done & (self._slot_rid >= 0)
         if not finished.any():
             return shed_out
@@ -852,60 +899,72 @@ class SlotScheduler(SchedulerHost):
         # retired-count and stall serving on recompiles.  Masked serving
         # reads the FULL ef-wide beam so voided top-k entries backfill from
         # the alive candidates the search already ranked at k..ef.
-        idx = np.flatnonzero(finished)
-        width = self.ef if self._masked else (self.k_c or self.k)
-        d = np.asarray(self.state.core.beam_d[:, :width])[idx]
-        ids = np.asarray(self.state.core.beam_i[:, :width]).astype(np.int64)[idx]
-        evals = np.asarray(self.state.core.n_evals)[idx]
-        hops = np.asarray(self.state.core.hops)[idx]
-        metas = [self._meta.pop(int(self._slot_rid[s]), (0.0, 0.0, 0, 0, 0, 0))
-                 for s in idx]
-        if self._masked and g.alive is not None:
-            # points tombstoned while this query was in flight must not
-            # surface: void them and compact each row (stable order).  The
-            # killed-epoch guard additionally catches slots that died AND
-            # were reused for a different point since this request's
-            # admission — `alive` alone would vouch for the impostor.
-            safe = np.where(ids >= 0, ids, 0)
-            dead = ~np.asarray(g.alive)[safe]
-            if g.killed_epoch is not None:
-                admit_epoch = np.asarray([m[2] for m in metas])[:, None]
-                dead |= g.killed_epoch[safe] > admit_epoch
-            dead &= ids >= 0
-            if dead.any():
-                d = np.where(dead, np.inf, d)
-                ids = np.where(dead, -1, ids)
-                order = np.argsort(np.where(np.isfinite(d), 0, 1), axis=1,
-                                   kind="stable")
-                d = np.take_along_axis(d, order, axis=1)
-                ids = np.take_along_axis(ids, order, axis=1)
+        with t.span("retire_read"):
+            idx = np.flatnonzero(finished)
+            width = self.ef if self._masked else (self.k_c or self.k)
+            d = np.asarray(self.state.core.beam_d[:, :width])[idx]
+            ids = np.asarray(
+                self.state.core.beam_i[:, :width]).astype(np.int64)[idx]
+            evals = np.asarray(self.state.core.n_evals)[idx]
+            hops = np.asarray(self.state.core.hops)[idx]
+        with t.span("retire"):
+            metas = [self._meta.pop(int(self._slot_rid[s]),
+                                    (0.0, 0.0, 0, 0, 0, 0, t.index))
+                     for s in idx]
+            counters["retired"] += len(idx)
+            counters["held_ticks"] += sum(t.index - m[6] + 1 for m in metas)
+            if self._masked and g.alive is not None:
+                # points tombstoned while this query was in flight must not
+                # surface: void them and compact each row (stable order).
+                # The killed-epoch guard additionally catches slots that
+                # died AND were reused for a different point since this
+                # request's admission — `alive` alone would vouch for the
+                # impostor.
+                safe = np.where(ids >= 0, ids, 0)
+                dead = ~np.asarray(g.alive)[safe]
+                if g.killed_epoch is not None:
+                    admit_epoch = np.asarray([m[2] for m in metas])[:, None]
+                    dead |= g.killed_epoch[safe] > admit_epoch
+                dead &= ids >= 0
+                if dead.any():
+                    d = np.where(dead, np.inf, d)
+                    ids = np.where(dead, -1, ids)
+                    order = np.argsort(np.where(np.isfinite(d), 0, 1),
+                                       axis=1, kind="stable")
+                    d = np.take_along_axis(d, order, axis=1)
+                    ids = np.take_along_axis(ids, order, axis=1)
+            if self.k_c is None:
+                d, ids = d[:, : self.k], ids[:, : self.k]
         if self.k_c is not None:
             # full-symmetrization scenario: the beam ran under the bound
             # search policy; re-rank its k_c best candidates under the
             # ORIGINAL distance at retire time (one fixed-shape B=1 call
             # per retired request, so serving never recompiles)
-            d, ids = d[:, : self.k_c], ids[:, : self.k_c]
-            rr_d = np.empty((len(idx), self.k), np.float32)
-            rr_i = np.empty((len(idx), self.k), np.int64)
-            for j, s in enumerate(idx):
-                rr_d[j], rr_i[j] = self._rerank_fn(self._slot_q[s], ids[j])
-            d, ids = rr_d, rr_i
-            evals = evals + self.k_c
-        else:
-            d, ids = d[:, : self.k], ids[:, : self.k]
+            with t.span("rerank"):
+                d, ids = d[:, : self.k_c], ids[:, : self.k_c]
+                rr_d = np.empty((len(idx), self.k), np.float32)
+                rr_i = np.empty((len(idx), self.k), np.int64)
+                for j, s in enumerate(idx):
+                    rr_d[j], rr_i[j] = self._rerank_fn(self._slot_q[s],
+                                                       ids[j])
+                d, ids = rr_d, rr_i
+                evals = evals + self.k_c
 
         out = []
-        for j, s in enumerate(idx):
-            rid = int(self._slot_rid[s])
-            t_arr, t_adm, _, tenant, priority, lvl = metas[j]
-            if now > t_adm:
-                # feed the admission controller's per-rung service estimate
-                self.admission.estimator.observe(now - t_adm, level=lvl)
-            out.append(SlotResult(rid=rid, dists=d[j], ids=ids[j],
-                                  n_evals=int(evals[j]), hops=int(hops[j]),
-                                  t_arrival=t_arr, t_admit=t_adm,
-                                  tenant=tenant, priority=priority,
-                                  level=lvl))
-            self._slot_rid[s] = -1
-        self.state = self._release(self.state, jnp.asarray(finished))
+        with t.span("retire"):
+            for j, s in enumerate(idx):
+                rid = int(self._slot_rid[s])
+                t_arr, t_adm, _, tenant, priority, lvl, _ = metas[j]
+                if now > t_adm:
+                    # feed the admission controller's per-rung service
+                    # estimate
+                    self.admission.estimator.observe(now - t_adm, level=lvl)
+                out.append(SlotResult(rid=rid, dists=d[j], ids=ids[j],
+                                      n_evals=int(evals[j]),
+                                      hops=int(hops[j]), t_arrival=t_arr,
+                                      t_admit=t_adm, tenant=tenant,
+                                      priority=priority, level=lvl))
+                self._slot_rid[s] = -1
+        with t.span("release"):
+            self.state = self._release(self.state, jnp.asarray(finished))
         return shed_out + out

@@ -58,6 +58,7 @@ import numpy as np
 
 from repro.core import (ANNIndex, RetrievalSpec, dispatch_cache_size,
                         get_distance, knn_scan, recall_at_k)
+from repro.core import telemetry
 from repro.core.metrics import speedup_model
 from repro.data.synthetic import lda_like_histograms, split_queries
 from repro.launch.mesh import make_auto_mesh
@@ -475,6 +476,8 @@ def build_and_serve(*, spec: RetrievalSpec | None = None,
             idx, Q, arrivals, k=k, ef_search=ef_search, slots=slots,
             frontier=cont_frontier, adaptive=adaptive_frontier,
         )
+        # the scheduler's tick log (the newest): each part's mean and max
+        phases = telemetry.latest().summary()
         cont = {
             "offered_qps": round(rate, 1),
             "slots": slots,
@@ -491,6 +494,10 @@ def build_and_serve(*, spec: RetrievalSpec | None = None,
                 float(np.percentile(s_lat, 99) / np.percentile(c_lat, 99)), 2),
             "p99_speedup_vs_dynamic": round(
                 float(np.percentile(d_lat, 99) / np.percentile(c_lat, 99)), 2),
+            "tick_phases": {
+                "ticks": phases["ticks"],
+                **{k: {p: round(v, 4) for p, v in phases[k].items()}
+                   for k in ("mean_ms", "max_ms")}},
         }
         stats["continuous"] = cont
         if verbose:

@@ -50,6 +50,11 @@ def test_serve_endpoints_search_churn_continuous():
     assert cont["dynamic_p99_ms"] > 0
     assert cont["dynamic_recall@k"] >= stats["recall@k"] - 0.02
     assert cont["p99_speedup_vs_dynamic"] > 0
+    # the scheduler's tick log: each part's mean and max, in ms
+    ph = cont["tick_phases"]
+    assert ph["ticks"] > 0 and ph["mean_ms"]["tick"] > 0
+    assert ph["max_ms"]["sync"] >= ph["mean_ms"]["sync"] > 0
+    assert ph["mean_ms"]["self"] >= 0
 
     # -- churn mutation endpoints (online mutable index underneath)
     churn = stats["churn"]
