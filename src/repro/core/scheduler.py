@@ -107,13 +107,36 @@ class SlotState(NamedTuple):
     """Device state of the S slots (all arrays fixed-shape)."""
 
     core: BatchBeamState  # per-slot beam state, leading axis S
-    occupied: jax.Array  # (S,) bool — slot holds an in-flight query
+    retire: jax.Array  # (S, 2·width + 3) i32 ``retire_record(core, width)``
     qc: Any  # per-slot prepped query constants, leading axis S
     t_cur: jax.Array  # (S,) i32 adaptive frontier width (== T when fixed)
     stall: jax.Array  # (S,) i32 steps since the slot's beam radius improved
     worst: jax.Array  # (S,) f32 beam radius watermark for the policy
     ef_act: jax.Array  # (S,) i32 effective beam width (== ef when undemoted)
     adapt: jax.Array  # (S,) bool — slot runs the adaptive frontier policy
+
+
+def retire_record(core: BatchBeamState, width: int) -> jax.Array:
+    """What the host needs to retire any slot, packed into one int32 array
+    so a tick reads it in one transfer: per slot ``done``, ``n_evals``,
+    ``hops``, then the first ``width`` beam distances (float32 bits,
+    exact) and ids.  Fixed-shape over all S slots: the host picks the
+    retired rows, so no per-retired-count program is ever compiled."""
+    return jnp.concatenate([
+        core.done.astype(jnp.int32)[:, None],
+        core.n_evals[:, None],
+        core.hops[:, None],
+        jax.lax.bitcast_convert_type(core.beam_d[:, :width], jnp.int32),
+        core.beam_i[:, :width],
+    ], axis=1)
+
+
+def unpack_retire(rec: np.ndarray, width: int):
+    """Host side of ``retire_record`` (whose column 0 is ``done``):
+    ``n_evals``, ``hops``, beam distances and ids (int64) of the rows of
+    ``rec``."""
+    d = np.ascontiguousarray(rec[:, 3:3 + width]).view(np.float32)
+    return rec[:, 1], rec[:, 2], d, rec[:, 3 + width:].astype(np.int64)
 
 
 @dataclass
@@ -599,6 +622,9 @@ class SlotScheduler(SchedulerHost):
         self.max_steps = int(n if max_steps is None else max_steps)
         self.steps_per_sync = int(max(1, steps_per_sync))
         self._masked = g.alive is not None
+        # beam columns a retire takes from the step's record (the masked
+        # backfill reads the whole beam instead, on retiring ticks only)
+        self._width = self.k_c or self.k
         self._n = n
         self._dtype = jax.tree.leaves(g.consts)[0].dtype
         self._use_pallas = use_pallas
@@ -673,6 +699,7 @@ class SlotScheduler(SchedulerHost):
     def _build_jits(self):
         S, ef, T, C = self.S, self.ef, self.T, self.C
         dist, n, max_steps = self.dist, self._n, self.max_steps
+        width = self._width
         patience = self.patience
         qos, any_adaptive = self._qos, self._any_adaptive
 
@@ -703,9 +730,10 @@ class SlotScheduler(SchedulerHost):
             # fill/descent phase, where sequential-order expansion is the
             # whole point of the policy
             t_new = jnp.where(ad_new, 1, T) if any_adaptive else T
+            core = jax.tree.map(sel, fresh, state.core)
             return SlotState(
-                core=jax.tree.map(sel, fresh, state.core),
-                occupied=state.occupied | write,
+                core=core,
+                retire=retire_record(core, width),
                 qc=jax.tree.map(sel, qc_new, state.qc),
                 t_cur=jnp.where(write, t_new, state.t_cur),
                 stall=jnp.where(write, 0, state.stall),
@@ -739,16 +767,12 @@ class SlotScheduler(SchedulerHost):
                         core, t_cur, stall, worst, T, patience, radius=radius
                     )
                     t_cur = jnp.where(state.adapt, t_cur, T)
-            return state._replace(core=core, t_cur=t_cur, stall=stall,
-                                  worst=worst)
-
-        @jax.named_scope("release")
-        def release(state: SlotState, freed):
-            return state._replace(occupied=state.occupied & ~freed)
+            return state._replace(core=core,
+                                  retire=retire_record(core, width),
+                                  t_cur=t_cur, stall=stall, worst=worst)
 
         self._admit = jax.jit(admit)
         self._step = jax.jit(step)
-        self._release = jax.jit(release)
 
     # ----------------------------------------------------------- state mgmt
 
@@ -771,7 +795,7 @@ class SlotScheduler(SchedulerHost):
         qc = jax.vmap(self.dist.prep_query)(q0)
         self.state = SlotState(
             core=core,
-            occupied=jnp.zeros((S,), bool),
+            retire=retire_record(core, self._width),
             qc=qc,
             t_cur=jnp.full((S,), self.T, jnp.int32),
             stall=jnp.zeros((S,), jnp.int32),
@@ -804,6 +828,11 @@ class SlotScheduler(SchedulerHost):
         }
 
     # -------------------------------------------------------------- serving
+
+    def _read(self, *arrays) -> tuple:
+        """Copy slot-state ``arrays`` to the host in one call: the tick's
+        only device-to-host reads go through here."""
+        return jax.device_get(arrays)
 
     def _tick(self, now: float, t) -> list[SlotResult]:
         """Admit pending requests into free slots (DRR across tenants,
@@ -890,23 +919,22 @@ class SlotScheduler(SchedulerHost):
                                     self._kernel_rows(g.consts))
 
         with t.span("sync"):
-            done = np.asarray(self.state.core.done)  # syncs the step
-        finished = done & (self._slot_rid >= 0)
+            # the tick's one read: waits out the step, returns its record
+            rec, = self._read(self.state.retire)
+        finished = rec[:, 0].astype(bool) & (self._slot_rid >= 0)
         if not finished.any():
             return shed_out
-        # fixed-shape device reads (full S rows, host-side row select): a
-        # per-retire fancy gather would compile one executable per distinct
-        # retired-count and stall serving on recompiles.  Masked serving
-        # reads the FULL ef-wide beam so voided top-k entries backfill from
-        # the alive candidates the search already ranked at k..ef.
+        # the retired rows are picked on the host from the fixed-shape
+        # record.  Masked serving takes the FULL ef-wide beam instead, so
+        # voided top-k entries backfill from the alive candidates the
+        # search already ranked at k..ef: a read only a retiring tick makes.
         with t.span("retire_read"):
             idx = np.flatnonzero(finished)
-            width = self.ef if self._masked else (self.k_c or self.k)
-            d = np.asarray(self.state.core.beam_d[:, :width])[idx]
-            ids = np.asarray(
-                self.state.core.beam_i[:, :width]).astype(np.int64)[idx]
-            evals = np.asarray(self.state.core.n_evals)[idx]
-            hops = np.asarray(self.state.core.hops)[idx]
+            evals, hops, d, ids = unpack_retire(rec[idx], self._width)
+            if self._masked:
+                d, ids = self._read(self.state.core.beam_d,
+                                    self.state.core.beam_i)
+                d, ids = d[idx], ids[idx].astype(np.int64)
         with t.span("retire"):
             metas = [self._meta.pop(int(self._slot_rid[s]),
                                     (0.0, 0.0, 0, 0, 0, 0, t.index))
@@ -964,7 +992,7 @@ class SlotScheduler(SchedulerHost):
                                       hops=int(hops[j]), t_arrival=t_arr,
                                       t_admit=t_adm, tenant=tenant,
                                       priority=priority, level=lvl))
+                # the slot stays frozen on the device (its ``done`` holds)
+                # until admission writes over it
                 self._slot_rid[s] = -1
-        with t.span("release"):
-            self.state = self._release(self.state, jnp.asarray(finished))
         return shed_out + out

@@ -34,9 +34,12 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 SPANS = ("select", "put", "admit", "background", "step", "sync",
-         "retire_read", "rerank", "retire", "release")
+         "retire_read", "rerank", "retire")
+# parts no tick runs any longer (retirement moved into the step program):
+# their columns stay, reading 0, for readers of the log that sum them
+GONE = ("release",)
 COUNTERS = ("admitted", "shed", "demoted", "retired", "held_ticks")
-COLUMNS = ("now", "tick", *SPANS, "occupied", *COUNTERS)
+COLUMNS = ("now", "tick", *SPANS, *GONE, "occupied", *COUNTERS)
 CAPACITY = 16_384  # rows kept: the newest overwrite the oldest
 
 _COL = {name: i for i, name in enumerate(COLUMNS)}

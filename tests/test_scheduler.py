@@ -7,7 +7,12 @@ counts.  Slot recycling (more queries than slots) must not change any
 query's result, only its admission time.  The adaptive frontier must cut
 distance evaluations at equal recall.  On a mutable index, mutations
 interleave with in-flight queries without surfacing tombstoned points.
+A tick reads the slot state once (the step's retire record) and dispatches
+at most an admit and a step; masked serving adds one read of the whole
+beam on ticks that retire.
 """
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -262,3 +267,68 @@ def test_scheduler_rerank_spec_on_mutable_index(setup):
         valid = r.ids[r.ids >= 0].astype(int)
         assert alive_now[valid].all(), (r.rid, r.ids)
         assert not np.isin(valid, victims).any()
+
+
+def _count_tick_calls(sched, monkeypatch):
+    """Wrap the scheduler's jitted programs and its host read of the slot
+    state, on the instance: per tick (its index in the tick log), the
+    programs dispatched by name and the shapes of each read."""
+    calls = collections.defaultdict(lambda: {"programs": [], "reads": []})
+
+    def program(name, fn):
+        def counted(*args):
+            calls[sched.log.n]["programs"].append(name)
+            return fn(*args)
+        return counted
+
+    for name in ("_admit", "_step"):
+        monkeypatch.setattr(sched, name, program(name, getattr(sched, name)))
+    read = sched._read
+
+    def counted_read(*arrays):
+        calls[sched.log.n]["reads"].append(tuple(a.shape for a in arrays))
+        return read(*arrays)
+
+    monkeypatch.setattr(sched, "_read", counted_read)
+    return calls
+
+
+@pytest.mark.parametrize("index", ["static", "masked"])
+def test_tick_reads_one_record_and_dispatches_no_release(setup, monkeypatch,
+                                                          index):
+    """More queries than slots, so slots are retired and refilled: every
+    tick that steps makes exactly one read (the (S, 2k + 3) retire record)
+    and dispatches at most admit and step.  The masked index reads its
+    ef-wide beam besides, on the ticks that retire and on no others."""
+    dist, Q, db, view = setup
+    S = 4
+    if index == "static":
+        sched = SlotScheduler(dist, lambda: view, dim=DIM, slots=S, ef=EF,
+                              k=K, frontier=4, use_pallas=False)
+    else:
+        idx = ANNIndex.build(db[:300], dist, builder="swgraph",
+                             build_engine="wave", wave=32, NN=10,
+                             ef_construction=48, capacity=400,
+                             key=jax.random.PRNGKey(2))
+        sched = idx.scheduler(K, EF, slots=S, frontier=4)
+    assert sched._masked == (index == "masked")
+    assert not hasattr(sched, "_release")
+    sched.warmup(np.asarray(Q[0]))
+    calls = _count_tick_calls(sched, monkeypatch)
+    for j in range(12):
+        sched.submit(np.asarray(Q[j]), rid=j)
+    got = []
+    while sched.n_pending or sched.n_inflight:
+        got += sched.tick()
+    assert sorted(r.rid for r in got) == list(range(12))
+    rows = sched.log.rows()
+    assert rows["admitted"][1:].sum() > 0  # slots were refilled
+    wide = ((S, EF), (S, EF))
+    for i in range(sched.log.n):
+        programs, reads = calls[i]["programs"], calls[i]["reads"]
+        assert programs.count("_step") == 1 and len(programs) <= 2, programs
+        assert set(programs) <= {"_admit", "_step"}
+        assert reads[0] == ((S, 2 * K + 3),)
+        assert reads[1:] == ([wide] if sched._masked and rows["retired"][i]
+                             else []), (i, reads)
+    assert set(calls) == set(range(sched.log.n))

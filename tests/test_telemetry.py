@@ -181,7 +181,7 @@ def test_spans_land_on_the_profiled_host_line(setup, tmp_path):
     names = _tick_span_names(trace)
     want = {"repro.tick"} | {"repro.tick." + p for p in
                              ("select", "put", "admit", "step", "sync",
-                              "retire_read", "retire", "release")}
+                              "retire_read", "retire")}
     assert names == want
     ticks = [e for e in trace.host if e.name == "repro.tick"]
     assert len(ticks) == sched.log.n
@@ -192,8 +192,8 @@ def test_spans_land_on_the_profiled_host_line(setup, tmp_path):
 
 
 def test_sharded_scheduler_writes_the_same_spans():
-    """The scatter-gather scheduler logs and annotates the same parts (it
-    has no release: admission writes over a retired slot)."""
+    """The scatter-gather scheduler logs and annotates the same parts
+    (neither has a release: admission writes over a retired slot)."""
     body = """
 import glob, json, sys, tempfile
 import jax, numpy as np
