@@ -21,6 +21,7 @@ from .symmetrize import (
     calibrate_tau,
     get_learned_weights,
     learned_weights_fingerprint,
+    linear_blend,
     register_learned_weights,
     symmetrized,
 )

@@ -173,6 +173,106 @@ def wave_connect(dist, consts, qc_all, adj, adj_d, pids, ok_pt, beam_i, beam_d,
     return reverse_edge_merge(adj, adj_d, flat_j, flat_i, d_rev, flat_ok, R)
 
 
+def wave_knobs(n: int, NN: int = 15, ef_construction: int = 100,
+               M_max: int | None = None, wave: int = 32,
+               rev_rounds: int | None = None, frontier: int | None = None,
+               intra_links: int | None = None) -> dict:
+    """The static shape of a wave build over ``n`` rows (see
+    ``build_swgraph_wave`` for the knobs): degree cap ``M_max``, beam width
+    ``ef``, wave size ``W``, reverse-merge rounds ``R``, frontier ``T``,
+    intra-wave links ``L``."""
+    if M_max is None:
+        M_max = 2 * NN
+    if M_max < NN:
+        raise ValueError(f"M_max {M_max} < NN {NN}")
+    W = int(max(1, min(wave, n - 1)))
+    return dict(
+        NN=NN, M_max=M_max, ef=max(ef_construction, NN), W=W,
+        R=int(min(W, 8 if rev_rounds is None else rev_rounds)),
+        T=int(frontier) if frontier is not None else (1 if W == 1 else 4),
+        L=int(min(NN if intra_links is None else intra_links, W - 1)),
+    )
+
+
+def wave_count(n: int, W: int) -> int:
+    """Waves of a build: point 0 is the seed, waves cover rows 1..n-1."""
+    return -(-(n - 1) // W)
+
+
+def wave_evals(steps: int, n: int, *, NN, M_max, ef, W, T, L, **_) -> int:
+    """Distances a wave build over ``n`` rows computed, given ``steps``, the
+    lock-steps its beams ran summed over the waves (``wave_insert``): per
+    wave, the seed entry and then a (W, T * M_max) candidate block per
+    lock-step for the beams, the (W, W) intra-wave block, and W * NN
+    reverse-edge scores.  Padding rows of the last wave count: they are
+    scored like the rest."""
+    per_wave = W * (1 + NN + (W if L > 0 else 0))
+    return int(steps) * W * min(T, ef) * M_max + wave_count(n, W) * per_wave
+
+
+@functools.partial(jax.jit, static_argnames=("dist",))
+def wave_prep(dist, X):
+    """The per-row constants a wave build scores with, prepped once: the
+    corpus reps (``prep_scan``) and every row's query constants
+    (``prep_query``), each row acting as its own query."""
+    return dist.prep_scan(X), jax.vmap(dist.prep_query)(X)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("dist", "NN", "M_max", "ef", "W", "R", "T", "L", "use_pallas"),
+)
+def wave_insert(dist, consts, qc_all, *, NN, M_max, ef, W, R, T, L, use_pallas=None):
+    """Insert rows 1..n-1 wave by wave (one ``lax.scan``) from the prepped
+    constants of ``wave_prep``; knobs from ``wave_knobs``.  Returns the
+    ``(n, M_max)`` adjacency and ``steps``, the lock-steps the waves' beams
+    ran in all (each wave's longest beam, plus the step that finds every
+    beam done), the count ``wave_evals`` turns into distances."""
+    n = jax.tree.leaves(qc_all)[0].shape[0]
+    n_waves = wave_count(n, W)
+    # point 0 is the seed node (no insertion); waves cover 1..n-1, padded
+    pids_all = 1 + jnp.arange(n_waves * W, dtype=jnp.int32).reshape(n_waves, W)
+
+    adj = jnp.full((n, M_max), -1, jnp.int32)
+    adj_d = jnp.full((n, M_max), INF, jnp.float32)
+    entries = jnp.zeros((1,), jnp.int32)
+
+    from repro.kernels import ops
+
+    # the kernel's row view of the corpus, laid out once per build
+    x_rows = ops.kernel_rows(dist, consts, use_pallas)
+
+    def wave_step(carry, pids):
+        adj, adj_d, steps = carry
+        base = pids[0]  # every point in the wave sees exactly the prefix
+        ok_pt = pids < n
+        safe_p = jnp.where(ok_pt, pids, 0)
+        qc = jax.tree.map(lambda a: a[safe_p], qc_all)
+
+        if x_rows is not None:
+
+            def score_rows(ids):
+                return ops.frontier_gather_scores(
+                    dist, ids, qc["rep"], qc["bias"], consts["rep"], consts["bias"],
+                    x_rows=x_rows,
+                )
+        else:
+
+            def score_rows(ids):
+                rows = jax.tree.map(lambda a: a[ids], consts)
+                return jax.vmap(dist.score)(rows, qc)
+
+        st = batched_beam_search(adj, score_rows, entries, W, ef, n_active=base, frontier=T)
+        adj, adj_d = wave_connect(
+            dist, consts, qc_all, adj, adj_d, pids, ok_pt, st.beam_i, st.beam_d,
+            NN=NN, L=L, R=R,
+        )
+        return (adj, adj_d, steps + jnp.max(st.hops) + 1), None
+
+    (adj, _, steps), _ = jax.lax.scan(wave_step, (adj, adj_d, jnp.int32(0)), pids_all)
+    return adj, steps
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -214,61 +314,20 @@ def build_swgraph_wave(
     jnp path runs, which is also what guarantees W=1 bit-parity with the
     sequential builder; True forces the kernel (interpret mode off-TPU),
     False forces jnp.  Composite distances always take the generic path.
+
+    One program: the composition of ``wave_prep`` and ``wave_insert``,
+    which ``ANNIndex.build`` runs as two, timing each.
     """
-    if M_max is None:
-        M_max = 2 * NN
-    assert M_max >= NN
-    n = X.shape[0]
-    consts = dist.prep_scan(X)
-    qc_all = jax.vmap(dist.prep_query)(X)
-    ef = max(ef_construction, NN)
-    W = int(max(1, min(wave, n - 1)))
-    R = int(min(W, 8 if rev_rounds is None else rev_rounds))
-    T = int(frontier) if frontier is not None else (1 if W == 1 else 4)
-    L = int(min(NN if intra_links is None else intra_links, W - 1))
-    n_waves = -(-(n - 1) // W)
-    # point 0 is the seed node (no insertion); waves cover 1..n-1, padded
-    pids_all = 1 + jnp.arange(n_waves * W, dtype=jnp.int32).reshape(n_waves, W)
+    knobs = wave_knobs(X.shape[0], NN, ef_construction, M_max, wave,
+                       rev_rounds, frontier, intra_links)
+    consts, qc_all = wave_prep(dist, X)
+    adj, _ = wave_insert(dist, consts, qc_all, use_pallas=use_pallas, **knobs)
+    return adj, graph_degrees(adj)
 
-    adj = jnp.full((n, M_max), -1, jnp.int32)
-    adj_d = jnp.full((n, M_max), INF, jnp.float32)
-    entries = jnp.zeros((1,), jnp.int32)
 
-    from repro.kernels import ops
-
-    # the kernel's row view of the corpus, laid out once per build
-    x_rows = ops.kernel_rows(dist, consts, use_pallas)
-
-    def wave_step(carry, pids):
-        adj, adj_d = carry
-        base = pids[0]  # every point in the wave sees exactly the prefix
-        ok_pt = pids < n
-        safe_p = jnp.where(ok_pt, pids, 0)
-        qc = jax.tree.map(lambda a: a[safe_p], qc_all)
-
-        if x_rows is not None:
-
-            def score_rows(ids):
-                return ops.frontier_gather_scores(
-                    dist, ids, qc["rep"], qc["bias"], consts["rep"], consts["bias"],
-                    x_rows=x_rows,
-                )
-        else:
-
-            def score_rows(ids):
-                rows = jax.tree.map(lambda a: a[ids], consts)
-                return jax.vmap(dist.score)(rows, qc)
-
-        st = batched_beam_search(adj, score_rows, entries, W, ef, n_active=base, frontier=T)
-        adj, adj_d = wave_connect(
-            dist, consts, qc_all, adj, adj_d, pids, ok_pt, st.beam_i, st.beam_d,
-            NN=NN, L=L, R=R,
-        )
-        return (adj, adj_d), None
-
-    (adj, adj_d), _ = jax.lax.scan(wave_step, (adj, adj_d), pids_all)
-    degrees = jnp.sum(adj >= 0, axis=1, dtype=jnp.int32)
-    return adj, degrees
+def graph_degrees(adj):
+    """Out-degree of each row of an adjacency (-1 padding)."""
+    return jnp.sum(adj >= 0, axis=1, dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------------------

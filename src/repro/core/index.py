@@ -31,9 +31,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import telemetry
 from .batched_beam import make_step_searcher, select_entries
 from .beam_search import make_batched_searcher
-from .build_engine import build_swgraph_wave
+from .build_engine import (graph_degrees, wave_count, wave_evals, wave_insert, wave_knobs,
+                           wave_prep)
 from .filter_refine import rerank
 from .nndescent import build_nndescent
 from .online import OnlineIndex
@@ -172,71 +174,91 @@ class ANNIndex:
             )
         if dist is None:
             dist = spec.base_distance()
+        wave_engine = (spec.builder, spec.build_engine) == ("swgraph", "wave")
+        record = telemetry.BuildRecord()
+        with record:
+            with record.part("prep"):
+                # resolve data-calibrated policy parameters (RankBlend
+                # tau=None) against the database ONCE; the spec itself stays
+                # as written so later spec-equality checks
+                # (searcher/scheduler) keep working
+                build_policy = spec.build_policy.resolve(dist, X)
+                search_policy = spec.search_policy.resolve(dist, X)
+                build_dist = build_policy.bind(dist, natural=natural)
+                search_dist = (search_policy.bind(dist, natural=natural)
+                               if spec.needs_rerank else dist)
+                if wave_engine:
+                    knobs = wave_knobs(
+                        X.shape[0], spec.NN, spec.ef_construction, spec.M_max,
+                        spec.wave, frontier=spec.build_frontier)
+                    consts, qc_all = jax.block_until_ready(wave_prep(build_dist, X))
 
-        # resolve data-calibrated policy parameters (RankBlend tau=None)
-        # against the database ONCE; the spec itself stays as written so
-        # later spec-equality checks (searcher/scheduler) keep working
-        build_policy = spec.build_policy.resolve(dist, X)
-        search_policy = spec.search_policy.resolve(dist, X)
-        build_dist = build_policy.bind(dist, natural=natural)
-        search_dist = (search_policy.bind(dist, natural=natural)
-                       if spec.needs_rerank else dist)
+            with record.part("waves"):
+                if wave_engine:
+                    from repro.kernels.ops import kernel_scores
 
-        if spec.builder == "swgraph":
-            if spec.build_engine == "wave":
-                neighbors, degrees = build_swgraph_wave(
-                    build_dist, X, NN=spec.NN,
+                    neighbors, steps = wave_insert(build_dist, consts, qc_all, **knobs)
+                    # the rep width each scored candidate brings (the sum of
+                    # a composite's branches)
+                    record.m_prime = sum(a.shape[1] for a in jax.tree.leaves(consts)
+                                         if a.ndim == 2)
+                    del consts, qc_all
+                    record.waves = wave_count(X.shape[0], knobs["W"])
+                    record.kernel = kernel_scores(build_dist, record.m_prime)
+                    record.build_evals = wave_evals(int(steps), X.shape[0], **knobs)
+                elif spec.builder == "swgraph":
+                    neighbors, degrees = build_swgraph(
+                        build_dist, X, NN=spec.NN,
+                        ef_construction=spec.ef_construction, M_max=spec.M_max,
+                    )
+                else:
+                    key = key if key is not None else jax.random.PRNGKey(0)
+                    neighbors, degrees = build_nndescent(
+                        build_dist, X, key, K=spec.NN, iters=spec.nnd_iters,
+                        M_out=spec.M_max,
+                    )
+                jax.block_until_ready(neighbors)
+
+            with record.part("finish"):
+                if wave_engine:
+                    degrees = graph_degrees(neighbors)
+                entries = select_entries(
+                    search_dist, X, n_entries=spec.n_entries,
+                    key=jax.random.fold_in(key, 0xE) if key is not None else None,
+                )
+
+                info = dict(
+                    builder=spec.builder,
+                    build_engine=spec.build_engine if spec.builder == "swgraph" else "nndescent",
+                    wave=spec.wave if wave_engine else None,
+                    index_sym=str(spec.build_policy),
+                    query_sym=str(spec.search_policy),
+                    # concrete policies actually bound (differ from the
+                    # spec's only when a data-calibrated parameter was
+                    # resolved at build time)
+                    index_sym_resolved=str(build_policy),
+                    query_sym_resolved=str(search_policy),
+                    NN=spec.NN,
                     ef_construction=spec.ef_construction,
-                    M_max=spec.M_max, wave=spec.wave,
-                    frontier=spec.build_frontier,
+                    mean_degree=float(jnp.mean(degrees.astype(jnp.float32))),
+                    spec=spec.to_dict(),
+                    spec_fingerprint=spec.fingerprint(),
                 )
-            else:
-                neighbors, degrees = build_swgraph(
-                    build_dist, X, NN=spec.NN,
-                    ef_construction=spec.ef_construction, M_max=spec.M_max,
+                idx = cls(
+                    X=X,
+                    neighbors=neighbors,
+                    dist=dist,
+                    search_dist=search_dist,
+                    query_sym=str(spec.search_policy),
+                    entries=entries,
+                    build_info=info,
+                    build_dist=build_dist,
+                    capacity=spec.capacity,
+                    spec=spec,
                 )
-        else:
-            key = key if key is not None else jax.random.PRNGKey(0)
-            neighbors, degrees = build_nndescent(
-                build_dist, X, key, K=spec.NN, iters=spec.nnd_iters,
-                M_out=spec.M_max,
-            )
-
-        entries = select_entries(
-            search_dist, X, n_entries=spec.n_entries,
-            key=jax.random.fold_in(key, 0xE) if key is not None else None,
-        )
-
-        info = dict(
-            builder=spec.builder,
-            build_engine=spec.build_engine if spec.builder == "swgraph" else "nndescent",
-            wave=spec.wave if (spec.builder, spec.build_engine) == ("swgraph", "wave") else None,
-            index_sym=str(spec.build_policy),
-            query_sym=str(spec.search_policy),
-            # concrete policies actually bound (differ from the spec's only
-            # when a data-calibrated parameter was resolved at build time)
-            index_sym_resolved=str(build_policy),
-            query_sym_resolved=str(search_policy),
-            NN=spec.NN,
-            ef_construction=spec.ef_construction,
-            mean_degree=float(jnp.mean(degrees.astype(jnp.float32))),
-            spec=spec.to_dict(),
-            spec_fingerprint=spec.fingerprint(),
-        )
-        idx = cls(
-            X=X,
-            neighbors=neighbors,
-            dist=dist,
-            search_dist=search_dist,
-            query_sym=str(spec.search_policy),
-            entries=entries,
-            build_info=info,
-            build_dist=build_dist,
-            capacity=spec.capacity,
-            spec=spec,
-        )
-        if spec.capacity is not None:
-            idx.ensure_online()
+                if spec.capacity is not None:
+                    idx.ensure_online()
+                jax.block_until_ready(idx.entries)
         return idx
 
     # ----------------------------------------------------------------- online

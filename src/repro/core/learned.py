@@ -25,7 +25,7 @@ calibration sample:
 The candidate family ALWAYS contains the degenerate clone of the hand
 anchor (``alpha = hand_alpha, beta = 0, tau = None``), which
 ``symmetrize.LearnedDistance`` evaluates with arithmetic bit-identical to
-``CombinedDistance`` blend — so with the shared build key the clone
+the distance ``Blend(hand_alpha)`` binds to — so with the shared build key the clone
 reproduces the anchor's graph, evals and recall exactly, and the selected
 candidate can never be worse than the anchor.  That by-construction
 guarantee is what the CI gate (``benchmarks/bench_learned.py``) leans on.

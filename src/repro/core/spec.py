@@ -42,7 +42,8 @@ import json
 import re
 from typing import Callable, Optional
 
-from .symmetrize import SYM_MODES, CombinedDistance, reverse_of, symmetrized
+from .symmetrize import (SYM_MODES, CombinedDistance, linear_blend, lowers_linearly,
+                         reverse_of, symmetrized)
 
 # ---------------------------------------------------------------------------
 # DistancePolicy
@@ -163,9 +164,13 @@ class DistancePolicy:
     def bind(self, base, natural: Optional[Callable] = None, data=None):
         """Lower the policy over ``base``, returning a PairDistance.
 
-        The exact special cases of ``Blend`` lower to the dedicated legacy
-        wrappers so ``Blend(0.5)`` is bit-identical to ``avg``, ``Blend(0)``
-        to ``reverse`` and ``Blend(1)`` to the original distance.
+        The exact special cases of ``Blend`` lower like the legacy modes,
+        so ``Blend(0.5)`` is bit-identical to ``avg``, ``Blend(0)`` to
+        ``reverse`` and ``Blend(1)`` to the original distance.  Over a
+        linear base (KL, Itakura-Saito) every ``Blend``, ``avg`` and
+        ``reverse`` lowers to one matmul-form ``Distance``
+        (``symmetrize.linear_blend``), scored like a registry distance;
+        every other policy or base binds to its composite wrapper.
 
         ``data`` — optional (n, m) database sample used to ``resolve``
         data-calibrated parameters (RankBlend tau) before lowering; an
@@ -189,6 +194,8 @@ class DistancePolicy:
                 return symmetrized(base, "avg")
             if self.alpha == 0.0:
                 return reverse_of(base)
+            if lowers_linearly(base):
+                return linear_blend(base, self.alpha)
             return CombinedDistance(base, "blend", alpha=self.alpha)
         p = self.resolve(base, data)
         return CombinedDistance(base, "rankblend", alpha=p.alpha, tau=p.tau)

@@ -26,6 +26,7 @@ so graph builders and searchers are agnostic to the symmetrization mode.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 from typing import Callable, Optional
@@ -33,7 +34,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from .distances import Distance, l2_squared
+from .distances import POST_LINEAR, Distance, l2_squared
 
 SYM_MODES = ("none", "avg", "min", "reverse", "l2", "natural")
 
@@ -97,7 +98,8 @@ class SymmetrizedDistance:
 
     Works over ANY PairDistance (including ViewedDistance / BM25): it pairs
     the base with its argument-reversal and combines - two matmul-form
-    evaluations per block.
+    evaluations per block.  ``symmetrized`` lowers avg over a linear base
+    to ``linear_blend`` instead; min is never linear.
     """
 
     base: object  # any PairDistance
@@ -214,8 +216,9 @@ class CombinedDistance:
 
         blend      alpha * d(u, v) + (1 - alpha) * d(v, u)
                    (avg at alpha=0.5, reverse at 0, the original at 1 —
-                   those exact cases are lowered to the dedicated wrappers
-                   by ``DistancePolicy.bind`` for bit-parity)
+                   those exact cases are lowered like the legacy modes by
+                   ``DistancePolicy.bind`` for bit-parity, and over a
+                   linear base every blend lowers to ``linear_blend``)
         max        max(d(u, v), d(v, u))  — the pessimistic symmetrization
         rankblend  alpha * d(u, v) + (1 - alpha) * proxy(d(v, u)) where
                    ``proxy(x) = tau * sign(x) * log1p(|x| / tau)`` is a
@@ -226,7 +229,8 @@ class CombinedDistance:
 
     Same PairDistance contract as every other wrapper: two matmul-form
     evaluations per block, ``prep_scan`` carries both branches as a pytree,
-    so the batched engines and kernels run it unchanged.
+    so the batched engines run it unchanged; the gather kernel takes only a
+    plain ``Distance``, so it scores on the jnp path.
     """
 
     base: object  # any PairDistance
@@ -364,16 +368,18 @@ class LearnedDistance:
     is a low-rank Mahalanobis map fit by margin-ranking against true-NN
     pairs under the ORIGINAL distance (``repro.core.learned``).  Unused
     branches are gated STATICALLY (``alpha == 1`` skips the reverse
-    branch, ``beta == 0`` skips the Mahalanobis branch), so the
+    branch, ``beta == 0`` skips the Mahalanobis branch), and with no proxy
+    over a linear base the blend part is ``linear_blend`` itself, so the
     degenerate weights ``(alpha=a, beta=0, tau=None)`` are arithmetically
-    identical to ``CombinedDistance(base, "blend", a)`` — the trainer's
+    identical to what ``Blend(a)`` binds to — the trainer's
     by-construction anchor guarantee relies on this bit-parity.
 
     ``L`` lives inside ``maha`` (an internal ``ViewedDistance`` whose view
     closes over the array), keeping this dataclass hashable as a static
     jit argument.  Same PairDistance contract as every other wrapper:
     ``prep_scan`` carries up to three branches as a pytree, so the batched
-    engines and Pallas kernels run it unchanged.
+    engines run it unchanged, on the jnp path (the kernels take only a
+    plain ``Distance``).
     """
 
     base: object  # any PairDistance
@@ -414,6 +420,15 @@ class LearnedDistance:
         return reverse_of(self.base)
 
     @property
+    def _blend(self):
+        """The blend part as ``Blend(alpha)`` binds it where that is one
+        lowered ``Distance`` (a linear base, no proxy), so the degenerate
+        weights keep their bit-parity with the hand blend; else None."""
+        if self.tau is None and self.alpha != 1.0 and lowers_linearly(self.base):
+            return linear_blend(self.base, self.alpha)
+        return None
+
+    @property
     def name(self):
         return f"{self.base.name}-learned({self.weights_fingerprint})"
 
@@ -436,18 +451,25 @@ class LearnedDistance:
             out = out + self.beta * m
         return out
 
+    def _plus_maha(self, out, m):
+        return out if m is None else out + self.beta * m
+
     def matrix(self, U, V):
-        rev = self.base.matrix(V, U).T if self.alpha != 1.0 else None
         m = self.maha.matrix(U, V) if self.beta != 0.0 else None
+        if self._blend is not None:
+            return self._plus_maha(self._blend.matrix(U, V), m)
+        rev = self.base.matrix(V, U).T if self.alpha != 1.0 else None
         return self._combine(self.base.matrix(U, V), rev, m)
 
     def query_matrix(self, Q, X, mode: str = "left"):
+        # the Mahalanobis term is symmetric, so its mode is irrelevant
+        m = self.maha.query_matrix(Q, X, mode=mode) if self.beta != 0.0 else None
+        if self._blend is not None:
+            return self._plus_maha(self._blend.query_matrix(Q, X, mode=mode), m)
         fwd = self.base.query_matrix(Q, X, mode=mode)
         rev = None
         if self.alpha != 1.0:
             rev = self.base.query_matrix(Q, X, mode="right" if mode == "left" else "left")
-        # the Mahalanobis term is symmetric, so its mode is irrelevant
-        m = self.maha.query_matrix(Q, X, mode=mode) if self.beta != 0.0 else None
         return self._combine(fwd, rev, m)
 
     def pairwise(self, u, v):
@@ -459,25 +481,33 @@ class LearnedDistance:
         return jax.vmap(self.pairwise)(U, V)
 
     def prep_scan(self, X):
-        out = {"f": self.base.prep_scan(X)}
-        if self.alpha != 1.0:
-            out["r"] = self._rev.prep_scan(X)
+        if self._blend is not None:
+            out = {"b": self._blend.prep_scan(X)}
+        else:
+            out = {"f": self.base.prep_scan(X)}
+            if self.alpha != 1.0:
+                out["r"] = self._rev.prep_scan(X)
         if self.beta != 0.0:
             out["m"] = self.maha.prep_scan(X)
         return out
 
     def prep_query(self, q):
-        out = {"f": self.base.prep_query(q)}
-        if self.alpha != 1.0:
-            out["r"] = self._rev.prep_query(q)
+        if self._blend is not None:
+            out = {"b": self._blend.prep_query(q)}
+        else:
+            out = {"f": self.base.prep_query(q)}
+            if self.alpha != 1.0:
+                out["r"] = self._rev.prep_query(q)
         if self.beta != 0.0:
             out["m"] = self.maha.prep_query(q)
         return out
 
     def score(self, rows, qc):
+        m = self.maha.score(rows["m"], qc["m"]) if self.beta != 0.0 else None
+        if self._blend is not None:
+            return self._plus_maha(self._blend.score(rows["b"], qc["b"]), m)
         fwd = self.base.score(rows["f"], qc["f"])
         rev = self._rev.score(rows["r"], qc["r"]) if self.alpha != 1.0 else None
-        m = self.maha.score(rows["m"], qc["m"]) if self.beta != 0.0 else None
         return self._combine(fwd, rev, m)
 
 
@@ -529,10 +559,66 @@ def calibrate_tau(base, X, *, max_rows: int = 256) -> float:
 # ---------------------------------------------------------------------------
 
 
+def lowers_linearly(base) -> bool:
+    """Whether a linear blend of ``base``'s two argument orders is itself
+    one matmul-form ``Distance`` (``linear_blend``): a plain ``Distance``
+    whose post-combine is ``s + bias_l + bias_r`` (KL, Itakura-Saito)."""
+    return isinstance(base, Distance) and base.post_id == POST_LINEAR
+
+
+@functools.lru_cache(maxsize=64)
+def linear_blend(base: Distance, alpha: float) -> Distance:
+    """alpha * d(u, v) + (1 - alpha) * d(v, u) as ONE matmul-form Distance.
+
+    For a ``POST_LINEAR`` base, d(u, v) = L(u) . R(v) + bl(u) + br(v), so
+
+        alpha d(u, v) + (1 - alpha) d(v, u)
+            = [alpha L(u) | (1 - alpha) R(u)] . [R(v) | L(v)]
+              + (alpha bl(u) + (1 - alpha) br(u))
+              + (alpha br(v) + (1 - alpha) bl(v))
+
+    exactly: one matmul over m' = 2m (m' = m for the pure reversal,
+    alpha = 0, which swaps the roles), so the batched engines score it like
+    any registry distance (the gather kernel too, where m' fits one of its
+    128-lane rows: ``kernels.ops.kernel_scores``).  ``pairwise`` stays
+    the definition, the blend of the base's ``pairwise`` in both orders.
+    Names follow the wrappers it replaces (``kl-avg``, ``kl-reverse``,
+    ``kl-blend(0.25)``).  Cached, so binding the same base twice gives the
+    same object (one jit cache entry).
+    """
+    if not lowers_linearly(base):
+        raise ValueError(f"{base.name} has no linear post-combine")
+    if not 0.0 <= alpha < 1.0:
+        raise ValueError(f"alpha must be in [0, 1), got {alpha}")
+    b = base
+    if alpha == 0.0:
+        return Distance(
+            name=f"{b.name}-reverse", post_id=POST_LINEAR,
+            prep_left=b.prep_right, prep_right=b.prep_left,
+            bias_left=b.bias_right, bias_right=b.bias_left,
+            pairwise=lambda u, v: b.pairwise(v, u),
+            symmetric=b.symmetric, needs_simplex=b.needs_simplex,
+        )
+    a, r = float(alpha), 1.0 - float(alpha)
+    name = f"{b.name}-avg" if alpha == 0.5 else f"{b.name}-blend({alpha:g})"
+    return Distance(
+        name=name, post_id=POST_LINEAR,
+        prep_left=lambda U: jnp.concatenate([a * b.prep_left(U), r * b.prep_right(U)], -1),
+        prep_right=lambda V: jnp.concatenate([b.prep_right(V), b.prep_left(V)], -1),
+        bias_left=lambda U: a * b.bias_left(U) + r * b.bias_right(U),
+        bias_right=lambda V: a * b.bias_right(V) + r * b.bias_left(V),
+        pairwise=lambda u, v: a * b.pairwise(u, v) + r * b.pairwise(v, u),
+        symmetric=alpha == 0.5 or b.symmetric, needs_simplex=b.needs_simplex,
+    )
+
+
 def reverse_of(base):
     """Argument reversal for any PairDistance.  ViewedDistance reverses by
     swapping its role views AND reversing the inner distance:
-    vd_rev(u, v) = vd(v, u) = inner(L(v), R(u)) = inner_rev(R(u), L(v))."""
+    vd_rev(u, v) = vd(v, u) = inner(L(v), R(u)) = inner_rev(R(u), L(v)).
+    A linear base lowers to one ``Distance`` with its roles swapped."""
+    if lowers_linearly(base):
+        return linear_blend(base, 0.0)
     if isinstance(base, ViewedDistance):
         return ViewedDistance(
             ReversedDistance(base.base),
@@ -553,6 +639,8 @@ def symmetrized(base, mode: str, natural: Optional[Callable] = None):
         return base
     if mode == "reverse":
         return reverse_of(base)
+    if mode == "avg" and lowers_linearly(base):
+        return linear_blend(base, 0.5)
     if mode in ("avg", "min"):
         return SymmetrizedDistance(base, mode)
     if mode == "l2":

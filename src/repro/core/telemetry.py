@@ -1,4 +1,5 @@
-"""Telemetry of the slot schedulers' host loop: spans and a tick log.
+"""Telemetry of the slot schedulers' host loop (spans and a tick log) and
+of the index build (spans and a build record).
 
 One mechanism with two outputs, both fed by the same span objects:
 
@@ -22,6 +23,15 @@ One mechanism with two outputs, both fed by the same span objects:
 A tick's self time is its duration less the sum of its parts.  ``latest``
 returns the log of the most recently constructed scheduler, so an
 exporter finds it without a handle on the scheduler.
+
+The index build (``ANNIndex.build``) runs the same way inside a
+``repro.build`` span with the child spans ``repro.build.<part>``
+(``BUILD_PARTS``: binding and prepping the constants, the wave scan, and
+everything after it), each ended by a ``block_until_ready`` so a part's
+host-clock seconds hold its device work.  Its ``BuildRecord`` keeps those
+seconds and what the build reports of itself (waves, the rep width the
+build scored at, whether the gather kernel scored it, the distances it
+computed); ``latest_build`` returns the newest.
 """
 
 from __future__ import annotations
@@ -42,10 +52,13 @@ COUNTERS = ("admitted", "shed", "demoted", "retired", "held_ticks")
 COLUMNS = ("now", "tick", *SPANS, *GONE, "occupied", *COUNTERS)
 CAPACITY = 16_384  # rows kept: the newest overwrite the oldest
 
+BUILD_PARTS = ("prep", "waves", "finish")
+
 _COL = {name: i for i, name in enumerate(COLUMNS)}
 _LABEL = {name: "repro.tick." + name for name in SPANS}
 
 _latest: Optional["TickLog"] = None
+_latest_build: Optional["BuildRecord"] = None
 
 
 def latest() -> Optional["TickLog"]:
@@ -53,15 +66,20 @@ def latest() -> Optional["TickLog"]:
     return _latest
 
 
+def latest_build() -> Optional["BuildRecord"]:
+    """The record of the most recently finished build (None before it)."""
+    return _latest_build
+
+
 class _Span:
-    """One part of a tick: a profiler annotation, and its duration added to
-    the tick's row."""
+    """One part of a tick or a build: a profiler annotation, and its
+    duration added to the row's column ``col``."""
 
     __slots__ = ("_row", "_col", "_ann", "_t0")
 
-    def __init__(self, row: list, name: str):
-        self._row, self._col = row, _COL[name]
-        self._ann = TraceAnnotation(_LABEL[name])
+    def __init__(self, row: list, col: int, label: str):
+        self._row, self._col = row, col
+        self._ann = TraceAnnotation(label)
 
     def __enter__(self):
         self._ann.__enter__()
@@ -87,7 +105,7 @@ class Tick:
         self.row[0] = float(now)
 
     def span(self, part: str) -> _Span:
-        return _Span(self.row, part)
+        return _Span(self.row, _COL[part], _LABEL[part])
 
     def set(self, name: str, value) -> None:
         self.row[_COL[name]] = value
@@ -171,3 +189,48 @@ class TickLog:
             "max_ms": {k: 1e3 * float(v.max()) if n else 0.0
                        for k, v in parts.items()},
         }
+
+
+class BuildRecord:
+    """One index build: ``with record:`` runs it in the ``repro.build`` span
+    and ``record.part(name)`` times a part of it (``BUILD_PARTS``).
+
+    ``seconds`` holds each part's host-clock seconds and ``total`` the
+    whole span's; what the build reports of itself goes in ``waves`` (the
+    wave count, None for builders without waves), ``m_prime`` (the rep
+    width it scored at), ``kernel`` (whether the gather kernel scored it)
+    and ``build_evals`` (the distances it computed).  Leaving the block
+    makes the record the one ``latest_build`` returns."""
+
+    def __init__(self):
+        self._row = [0.0] * len(BUILD_PARTS)
+        self.total = 0.0
+        self.waves: Optional[int] = None
+        self.m_prime: Optional[int] = None
+        self.kernel = False
+        self.build_evals: Optional[int] = None
+
+    @property
+    def seconds(self) -> dict:
+        return dict(zip(BUILD_PARTS, self._row))
+
+    def part(self, name: str) -> _Span:
+        return _Span(self._row, BUILD_PARTS.index(name), "repro.build." + name)
+
+    def __enter__(self):
+        self._ann = TraceAnnotation("repro.build")
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        global _latest_build
+        self.total = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        if exc[0] is None:
+            _latest_build = self
+
+    def as_dict(self) -> dict:
+        return {"seconds": self.seconds, "total": self.total,
+                "waves": self.waves, "m_prime": self.m_prime,
+                "kernel": self.kernel, "build_evals": self.build_evals}

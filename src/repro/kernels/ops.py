@@ -21,7 +21,7 @@ from repro.core.distances import Distance
 from . import ref as _ref
 from .distance_matrix import distance_matrix as _dm_kernel
 from .frontier_gather import frontier_scores as _fs_kernel
-from .frontier_gather import row_view
+from .frontier_gather import LANES, row_view
 
 
 def _on_tpu() -> bool:
@@ -56,19 +56,32 @@ def beam_gather_scores(dist: Distance, ids, Q, X, use_pallas=None):
     )
 
 
+def kernel_scores(dist, m_prime: int, use_pallas=None) -> bool:
+    """Whether the fused gather kernel scores ``dist`` at rep width
+    ``m_prime``: only a plain ``Distance`` (registry distances, and the
+    linear blends that ``symmetrize.linear_blend`` lowers to one), and by
+    ``use_pallas``: True forces it (interpret mode off-TPU), False never
+    takes it, None takes it only on TPU and only where a rep fits one
+    128-lane row (K = 1 row DMA a candidate).  At m' > 128 the kernel
+    issues K row DMAs a candidate, and XLA's gather is faster: at m' = 256
+    it scored a (128, 120) candidate block in 264 us against the kernel's
+    776 us on one v5e chip.  Composite distances always score through
+    jnp."""
+    if not isinstance(dist, Distance) or use_pallas is False:
+        return False
+    return use_pallas is True or (_on_tpu() and m_prime <= LANES)
+
+
 def kernel_rows(dist, consts, use_pallas=None):
     """The corpus reps ``consts["rep"]`` (from ``dist.prep_scan``) laid out
     for the fused gather kernel (``row_view``), or None where the jnp path
-    scores.
+    scores (``kernel_scores``).
 
-    ``use_pallas=None`` picks the kernel only on TPU (the interpret path is
-    a per-tile Python loop — correct but slow off-TPU); True forces it,
-    False never takes it.  Composite distances always score through jnp.
     Engines call this ONCE where they prep the corpus and hand the result
     to every ``frontier_gather_scores`` call.
     """
-    if isinstance(dist, Distance) and (
-            use_pallas is True or (use_pallas is None and _on_tpu())):
+    if isinstance(dist, Distance) and kernel_scores(dist, consts["rep"].shape[-1],
+                                                    use_pallas):
         return row_view(consts["rep"])
     return None
 

@@ -212,3 +212,39 @@ def test_build_sharded_single_shard_smoke(data):
     _, ids, _, _ = idx_like.search(Q, k=K, ef_search=80)
     r = recall_at_k(np.asarray(ids), np.asarray(true_ids))
     assert r >= 0.85, r
+
+
+def test_lowered_avg_build_matches_composite():
+    """KL-avg lowered to one Distance builds the graph the composite
+    ``SymmetrizedDistance`` builds: same key, n = 2,000, m = 16.  Neighbour
+    lists agree, except where the two orders of one list hold ids at equal
+    distance (ties within 1e-6 relative), and KL recall@10 over both graphs
+    agrees within 0.01."""
+    from repro.core.batched_beam import make_step_searcher
+    from repro.core.distances import Distance
+    from repro.core.symmetrize import SymmetrizedDistance
+
+    X = lda_like_histograms(jax.random.PRNGKey(5), 2000 + 64, 16)
+    Q, db = split_queries(X, 64, jax.random.PRNGKey(6))
+    kl = get_distance("kl")
+    lowered, composite = symmetrized(kl, "avg"), SymmetrizedDistance(kl, "avg")
+    assert type(lowered) is Distance
+    graphs = [np.asarray(build_swgraph_wave(d, db, NN=10, ef_construction=48, wave=32)[0])
+              for d in (lowered, composite)]
+    differ = np.flatnonzero(np.any(graphs[0] != graphs[1], axis=1))
+    for i in differ:
+        ds = []
+        for g in graphs:
+            nb = g[i][g[i] >= 0]
+            ds.append(np.sort(np.asarray(composite.query_matrix(db[i][None], db[nb]))[0]))
+        assert len(ds[0]) == len(ds[1]), i
+        np.testing.assert_allclose(ds[0], ds[1], rtol=1e-6)
+
+    _, true_ids = knn_scan(kl, Q, db, K)
+    recalls = []
+    for g in graphs:
+        search = make_step_searcher(kl, jnp.asarray(g), db, 64, K,
+                                    entries=jnp.zeros((1,), jnp.int32), frontier=2)
+        _, ids, _, _ = search(Q)
+        recalls.append(recall_at_k(np.asarray(ids), np.asarray(true_ids)))
+    assert abs(recalls[0] - recalls[1]) <= 0.01, recalls

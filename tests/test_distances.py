@@ -174,3 +174,35 @@ def test_ranking_matmuls_pin_highest_precision(name):
     ]
     for lo in lowered:
         assert set(_dot_precisions(lo)) == {("HIGHEST", "HIGHEST")}
+
+
+@pytest.mark.parametrize("m", [16, 128])
+@pytest.mark.parametrize("alpha", [0.0, 0.25, 0.5, 0.75])
+@pytest.mark.parametrize("name", ["kl", "itakura_saito"])
+def test_linear_blend_matches_definition(name, alpha, m):
+    """alpha*d(u,v) + (1-alpha)*d(v,u) lowered to one matmul-form Distance
+    (m' = 2m, m' = m for the reversal) against the definition, through every
+    entry point.  rtol 1e-5: one float32 matmul over <= 256 terms against
+    two pointwise sums of m terms."""
+    from repro.core.spec import Blend
+
+    base = D.get_distance(name)
+    dist = Blend(alpha).bind(base)
+    assert type(dist) is D.Distance and dist.post_id == D.POST_LINEAR
+    U = _hists(30 + m, 6, m)
+    V = _hists(31 + m, 5, m)
+    assert dist.prep_left(U).shape[1] == (m if alpha == 0.0 else 2 * m)
+
+    def definition(u, v):
+        return alpha * base.pairwise(u, v) + (1.0 - alpha) * base.pairwise(v, u)
+
+    want = np.array([[definition(u, v) for v in V] for u in U])
+    np.testing.assert_allclose(dist.matrix(U, V), want, rtol=1e-5)
+    np.testing.assert_allclose(dist.query_matrix(V, U, mode="left"), want.T, rtol=1e-5)
+    np.testing.assert_allclose(dist.query_matrix(U, V, mode="right"), want, rtol=1e-5)
+    consts = dist.prep_scan(U)
+    for j in range(V.shape[0]):
+        np.testing.assert_allclose(dist.score(consts, dist.prep_query(V[j])),
+                                   want[:, j], rtol=1e-5)
+    np.testing.assert_allclose(dist.pairwise_batch(U[:5], V), np.diagonal(want),
+                               rtol=1e-5)

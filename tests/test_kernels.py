@@ -96,6 +96,31 @@ def test_frontier_kernel_row_views(name, B, R, m):
     assert bool(jnp.all(jnp.isinf(got[ids < 0])))
 
 
+@pytest.mark.parametrize("name,alpha", [("kl", 0.5), ("itakura_saito", 0.25)])
+def test_frontier_gather_scores_lowered_blend(name, alpha):
+    """A linear blend lowered to one Distance (m' = 256 over m = 128) is
+    scored by the kernel at K = 2 row DMAs per candidate, interpreted,
+    like the jnp reference scores it."""
+    from repro.core.spec import Blend
+    from repro.kernels.ops import frontier_gather_scores, kernel_rows
+
+    dist = Blend(alpha).bind(get_distance(name))
+    B, R, n, m = 11, 24, 37, 128
+    Q = random_histograms(jax.random.PRNGKey(40), B, m)
+    X = random_histograms(jax.random.PRNGKey(41), n, m)
+    consts = dist.prep_scan(X)
+    qc = jax.vmap(dist.prep_query)(Q)
+    x_rows = kernel_rows(dist, consts, use_pallas=True)
+    assert consts["rep"].shape == (n, 256) and x_rows.shape == (2 * n, 128)
+    ids = jax.random.randint(jax.random.PRNGKey(42), (B, R), -1, n)
+    got = frontier_gather_scores(dist, ids, qc["rep"], qc["bias"], consts["rep"],
+                                 consts["bias"], x_rows=x_rows)
+    want = kref.gather_scores_ref(ids, qc["rep"], consts["rep"], qc["bias"],
+                                  consts["bias"], dist.post_id, dist.c0)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert bool(jnp.all(jnp.isinf(got[ids < 0])))
+
+
 def test_ops_wrappers_match_distance_object():
     """ops.query_distance_matrix == Distance.query_matrix (the library path)."""
     dist = get_distance("itakura_saito")

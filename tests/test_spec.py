@@ -69,12 +69,25 @@ def test_policy_validation():
 
 
 def test_blend_special_cases_lower_to_legacy_wrappers():
-    from repro.core.symmetrize import ReversedDistance, SymmetrizedDistance
+    """Over a linear base (KL) avg, reverse and every blend lower to one
+    matmul-form ``Distance``; over Renyi the legacy wrappers stay."""
+    from repro.core.distances import Distance
+    from repro.core.symmetrize import (CombinedDistance, ReversedDistance,
+                                       SymmetrizedDistance, symmetrized)
 
     dist = get_distance("kl")
     assert Blend(1.0).bind(dist) is dist
-    assert isinstance(Blend(0.5).bind(dist), SymmetrizedDistance)
-    assert isinstance(Blend(0.0).bind(dist), ReversedDistance)
+    assert type(Blend(0.5).bind(dist)) is Distance
+    assert Blend(0.5).bind(dist) is symmetrized(dist, "avg")
+    assert Blend(0.5).bind(dist).name == "kl-avg"
+    assert type(Blend(0.0).bind(dist)) is Distance
+    assert type(Blend(0.25).bind(dist)) is Distance
+
+    renyi = get_distance("renyi_2")
+    assert Blend(1.0).bind(renyi) is renyi
+    assert isinstance(Blend(0.5).bind(renyi), SymmetrizedDistance)
+    assert isinstance(Blend(0.0).bind(renyi), ReversedDistance)
+    assert isinstance(Blend(0.25).bind(renyi), CombinedDistance)
 
 
 # ---------------------------------------------------------------------------

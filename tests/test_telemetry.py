@@ -11,9 +11,12 @@ What must hold:
   * ``reset`` (and so ``warmup``) empties the log; the ring keeps the
     newest ``CAPACITY`` rows and says how many it dropped;
   * under a profiler the spans land on the host line of the enclosing
-    annotation, and the sharded scheduler records the same names.
+    annotation, and the sharded scheduler records the same names;
+  * the build record's parts add up to the build, and its ``build_evals``
+    is every distance the build computed.
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -23,7 +26,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import ANNIndex, RetrievalSpec, telemetry
+from repro.core import ANNIndex, RetrievalSpec, get_distance, telemetry
 from repro.data.synthetic import lda_like_histograms, split_queries
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -242,3 +245,93 @@ print(json.dumps({
     assert got["annotated"] == got["ticks"] > 0
     assert got["admitted"] == got["retired"] == got["results"] == 12
     assert got["held"] >= got["retired"] and got["self_ok"]
+
+
+@dataclasses.dataclass(frozen=True)
+class _CountingKL:
+    """KL with a ``score`` that adds the size of every block it scores to
+    ``counts`` as the build runs (a composite: the jnp path scores it)."""
+
+    counts: list = dataclasses.field(compare=False, hash=False)
+    base: object = dataclasses.field(default_factory=lambda: get_distance("kl"))
+
+    name = "kl"
+    needs_simplex = True
+
+    def matrix(self, U, V):
+        return self.base.matrix(U, V)
+
+    def query_matrix(self, Q, X, mode="left"):
+        return self.base.query_matrix(Q, X, mode)
+
+    def pairwise(self, u, v):
+        return self.base.pairwise(u, v)
+
+    def prep_scan(self, X):
+        return self.base.prep_scan(X)
+
+    def prep_query(self, q):
+        return self.base.prep_query(q)
+
+    def score(self, rows, qc):
+        out = self.base.score(rows, qc)
+        jax.debug.callback(lambda o: self.counts.append(o.size), out)
+        return out
+
+
+@pytest.mark.parametrize("wave,frontier", [(16, None), (1, None), (8, 2)])
+def test_build_record_counts_every_distance(wave, frontier):
+    """``build_evals`` equals the distances a wrapped ``score`` saw over a
+    tiny build, and the record's parts add up to the build's time."""
+    X = lda_like_histograms(jax.random.PRNGKey(3), 150, 16)
+    spec = RetrievalSpec(distance="kl", builder="swgraph", NN=6,
+                         ef_construction=24, wave=wave, build_frontier=frontier)
+    dist = _CountingKL([])
+    ANNIndex.build(X, dist, spec=spec)
+    rec = telemetry.latest_build()
+    assert rec.build_evals == sum(dist.counts) > 0
+    assert rec.waves == -(-149 // wave) and rec.m_prime == 16 and not rec.kernel
+    parts = rec.seconds
+    assert set(parts) == set(telemetry.BUILD_PARTS) and min(parts.values()) > 0
+    assert sum(parts.values()) <= rec.total <= sum(parts.values()) + 0.05
+
+
+@pytest.mark.parametrize("policy,base,kernel,m_prime", [
+    ("avg", "kl", True, 32), ("blend(0.25)", "itakura_saito", True, 32),
+    ("reverse", "kl", True, 16), ("none", "kl", True, 16),
+    ("min", "kl", False, 32), ("max", "kl", False, 32),
+    ("avg", "renyi_2", False, 32), ("rankblend(0.5,1.0)", "kl", False, 32),
+])
+def test_build_record_names_the_scoring_path(policy, base, kernel, m_prime,
+                                             monkeypatch):
+    """Which path scores a build: linear policies over a linear base lower
+    to one Distance that the gather kernel can take, at m' = 2m for a blend;
+    every other policy or base stays composite and scores through jnp on
+    any backend (its branches' widths add up in ``m_prime``).  On the chip
+    the kernel takes by default only reps of one 128-lane row.  Off the
+    chip the record says jnp for all of them."""
+    from repro.core.spec import DistancePolicy
+    from repro.kernels import ops
+
+    bound = DistancePolicy.parse(policy).bind(get_distance(base))
+    assert ops.kernel_scores(bound, m_prime, use_pallas=True) is kernel
+    assert ops.kernel_scores(bound, m_prime, use_pallas=False) is False
+    with monkeypatch.context() as mp:
+        mp.setattr(ops, "_on_tpu", lambda: True)
+        assert ops.kernel_scores(bound, m_prime) is kernel
+        assert ops.kernel_scores(bound, 2 * ops.LANES) is False
+    X = lda_like_histograms(jax.random.PRNGKey(4), 40, 16)
+    spec = RetrievalSpec(distance=base, build_policy=policy, builder="swgraph",
+                         NN=4, ef_construction=8, wave=8, n_entries=1)
+    ANNIndex.build(X, spec=spec)
+    rec = telemetry.latest_build()
+    assert (rec.kernel, rec.m_prime) == (False, m_prime)
+
+
+def test_no_build_record_for_a_failed_build():
+    before = telemetry.latest_build()
+    X = lda_like_histograms(jax.random.PRNGKey(4), 20, 16)
+    with pytest.raises(ValueError):
+        ANNIndex.build(X, spec=RetrievalSpec(distance="kl", builder="swgraph",
+                                             NN=8, M_max=4))
+    assert telemetry.latest_build() is before

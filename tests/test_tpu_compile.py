@@ -26,7 +26,7 @@ from repro.kernels.distance_matrix import distance_matrix
 from repro.kernels.frontier_gather import LANES, frontier_scores
 
 N = 1_000_000
-WIDTHS = [8, 32, 128, 2048]
+WIDTHS = [8, 32, 128, 256, 2048]
 # M_max = 2*NN = 30 at frontier 1, the serve default frontier 4, a
 # scheduler frontier of 12, and NN-descent's K*K + K + n_random join
 RS = [30, 120, 360, 15 * 15 + 15 + 8]
@@ -135,3 +135,21 @@ def test_search_step_lays_out_no_corpus_copy(one_chip, on_tpu, m, n):
                       text)
     assert made and set(made) <= {"parameter", "get-tuple-element"}, made
     assert c.memory_analysis().temp_size_in_bytes < rows.size * 4
+
+
+@pytest.mark.parametrize("use_pallas", [None, True])
+def test_symmetrized_build_compiles(one_chip, on_tpu, use_pallas):
+    """The wave build under KL-avg, lowered to one Distance (m' = 256), at
+    n = 250,000 and the wiki-128 knobs: the whole wave scan compiles for the
+    chip.  By default it scores with XLA's gather (the kernel takes only
+    reps of one 128-lane row); forced, with the gather kernel at K = 2 row
+    DMAs a candidate."""
+    from repro.core.build_engine import wave_insert, wave_knobs
+    from repro.core.spec import DistancePolicy
+
+    n = 250_000
+    dist = DistancePolicy.parse("avg").bind(KL)
+    consts = {"rep": _shape(one_chip, (n, 256)), "bias": _shape(one_chip, (n,))}
+    c = wave_insert.lower(dist, consts, consts, use_pallas=use_pallas,
+                          **wave_knobs(n, NN=15, ef_construction=100, wave=128)).compile()
+    assert ("tpu_custom_call" in c.as_text()) is bool(use_pallas)
